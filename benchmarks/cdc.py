@@ -171,6 +171,9 @@ def main(argv=None) -> int:
     ap.add_argument("--reps", type=int, default=3)
     ap.add_argument("--out", default="BENCH_cdc.json")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.smoke:
         args.batch_mb = min(args.batch_mb, 1.0)
         args.scalar_kb = min(args.scalar_kb, 32)

@@ -264,6 +264,9 @@ def main() -> int:
     ap.add_argument("--shards", type=int, nargs="+", default=[1, 2, 4, 8])
     ap.add_argument("--out", default="BENCH_cluster.json")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.smoke:
         args.requests = min(args.requests, 30_000)
         args.workloads = args.workloads[:1]

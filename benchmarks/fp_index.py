@@ -209,6 +209,9 @@ def main(argv=None) -> int:
     ap.add_argument("--shards", type=int, default=4)
     ap.add_argument("--out", default="BENCH_fp_index.json")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.smoke:
         # keep the probe batch >= 100k: that scale IS the gate's contract
         args.resident = min(args.resident, 120_000)

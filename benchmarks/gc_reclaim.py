@@ -131,6 +131,9 @@ def main() -> int:
     ap.add_argument("--max-moves", type=int, default=512, help="per-shard compaction budget")
     ap.add_argument("--out", default="BENCH_gc.json")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.smoke:
         args.requests = min(args.requests, 12_000)
         args.chunk = min(args.chunk, 1024)

@@ -1,9 +1,11 @@
 """Kernel + data-plane throughput benchmarks.
 
-Wall-clock numbers on this CPU container measure the *interpret-mode* kernel
-(correctness vehicle); the derived column reports the analytic TPU roofline
-for the same schedule: the fingerprint kernel is memory-bound (reads every
-block once, writes 16 B/block), so its ceiling is HBM bandwidth.
+Wall-clock numbers off the chip measure the *interpret-mode* kernel
+(correctness vehicle); the derived column reports the analytic roofline of
+the device the run sees, from its HBM rate (``HBM_BYTES_PER_S``, keyed by
+``device_kind``; an unknown device is an error, not a default): the
+fingerprint kernel is memory-bound (reads every block once, writes
+16 B/block), so its ceiling is HBM bandwidth.
 """
 
 from __future__ import annotations
@@ -15,7 +17,19 @@ import numpy as np
 
 from repro.kernels.ops import ffh_counts, fingerprint_blocks, fingerprint_ints
 
-HBM_BW = 819e9  # v5e
+# Published HBM bandwidth per chip, by jax ``device_kind`` (Google Cloud
+# documentation, "TPU v5e": 819 GB/s).
+HBM_BYTES_PER_S = {"TPU v5 lite": 819e9}
+
+
+def hbm_bytes_per_s() -> float:
+    """HBM rate of the device this process runs on; raises if unknown."""
+    import jax
+
+    kind = jax.devices()[0].device_kind
+    if kind not in HBM_BYTES_PER_S:
+        raise KeyError(f"no HBM rate recorded for device kind {kind!r}")
+    return HBM_BYTES_PER_S[kind]
 
 
 def _time(fn, *args, reps=3):
@@ -39,7 +53,7 @@ def bench_fingerprint() -> List[dict]:
             "bench": "fingerprint_kernel", "blocks": b, "words": w,
             "us_per_call_interpret": round(dt * 1e6, 1),
             "interpret_gbps": round(gb / dt, 3),
-            "tpu_roofline_us": round((b * w * 4 + b * 16) / HBM_BW * 1e6, 2),
+            "roofline_us": round((b * w * 4 + b * 16) / hbm_bytes_per_s() * 1e6, 2),
         })
     return rows
 
@@ -89,7 +103,7 @@ def bench_paged_attention() -> List[dict]:
     return [{
         "bench": "paged_attention_kernel", "batch": B, "pages": pps,
         "us_per_call_interpret": round(dt * 1e6, 1),
-        "tpu_roofline_us": round(cache_gb / (819e9 / 1e9) * 1e6, 2),
+        "roofline_us": round(cache_gb * 1e9 / hbm_bytes_per_s() * 1e6, 2),
     }]
 
 

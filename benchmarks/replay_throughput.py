@@ -114,6 +114,9 @@ def main() -> int:
     )
     ap.add_argument("--out", default="BENCH_replay.json")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.smoke:
         args.requests = min(args.requests, 30_000)
         args.workloads = args.workloads[:1]

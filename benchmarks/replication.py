@@ -142,6 +142,9 @@ def main() -> int:
     ap.add_argument("--batch", type=int, default=2048)
     ap.add_argument("--out", default="BENCH_replication.json")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.smoke:
         args.requests = min(args.requests, 8_000)
         args.batch = min(args.batch, 512)

@@ -130,6 +130,9 @@ def main() -> int:
     )
     ap.add_argument("--out", default="BENCH_resharding.json")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if len(args.transitions) % 2:
         ap.error("--transitions takes from/to pairs")
     transitions = list(zip(args.transitions[::2], args.transitions[1::2]))

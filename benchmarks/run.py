@@ -12,7 +12,9 @@ replication) as
 subprocesses — each still writes its own ``BENCH_*.json`` — and merges
 every payload plus each bench's gate verdict into one
 ``BENCH_summary.json``, so the perf trajectory across PRs is one file
-instead of eight.  Exit code 1 if any bench's gate failed.
+instead of eight.  Exit code 1 if any bench's gate failed.  The ``--suite``
+parent never imports JAX: a chip belongs to one process, so only the
+children may take it.
 
 Usage:
     PYTHONPATH=src python -m benchmarks.run [--full] [--only fig6,...]
@@ -27,8 +29,6 @@ import os
 import subprocess
 import sys
 import time
-
-from . import kernel_bench, paper_validation, roofline
 
 # (suite name, script, emitted JSON) — run order is cheap-first
 SUITE = [
@@ -103,6 +103,11 @@ def main() -> None:
     args = ap.parse_args()
     if args.suite:
         raise SystemExit(run_suite(args.smoke))
+    from repro.compile_cache import enable_compile_cache
+
+    from . import kernel_bench, paper_validation, roofline
+
+    enable_compile_cache()
     n = 600_000 if args.full else 250_000
     only = set(args.only.split(",")) if args.only else None
 

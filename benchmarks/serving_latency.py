@@ -238,6 +238,9 @@ def main() -> int:
     ap.add_argument("--max-pending", type=int, default=16384)
     ap.add_argument("--out", default="BENCH_serving.json")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.smoke:
         args.requests = min(args.requests, 12_000)
         args.conns_per_tenant = 8

@@ -12,8 +12,8 @@ same exactness contract as ``core.fp_index``:
   to the host (greedy min/max selection is inherently sequential but
   O(#chunks)); the bytes never do.  Default on TPU.
 * ``numpy``   — vectorized windowed-sum candidates + one batched fingerprint
-  call over the packed chunk matrix.  Default off-TPU (interpret-mode Pallas
-  is a correctness artifact, not a fast path).
+  call over the packed chunk matrix.  Default on the CPU (interpret-mode
+  Pallas is a correctness artifact, not a fast path).
 * ``scalar``  — the per-byte reference oracle (``chunk_boundaries_scalar``):
   the literal rolling-hash recurrence + per-chunk fingerprints.  The other
   two backends are property-tested bit-exact against it.
@@ -147,7 +147,8 @@ class ContentDefinedChunker:
     """Byte buffers -> (chunk ends, chunk fingerprints) -> ReplayBatch.
 
     ``backend`` is ``"pallas"`` / ``"numpy"`` / ``"scalar"`` or ``None`` for
-    the platform default (pallas on TPU, numpy elsewhere) — all bit-exact.
+    the platform default (pallas on TPU, numpy on the CPU, an error on any
+    other platform) — all bit-exact.
     """
 
     def __init__(self, min_size: int = 2048, avg_size: int = 4096,
@@ -160,8 +161,8 @@ class ContentDefinedChunker:
     def _resolve(self) -> str:
         if self.backend is not None:
             return self.backend
-        import jax
-        return "pallas" if jax.default_backend() == "tpu" else "numpy"
+        from ..kernels.ops import device_platform
+        return "pallas" if device_platform() == "tpu" else "numpy"
 
     # -- boundaries ---------------------------------------------------------
 
@@ -197,7 +198,9 @@ class ContentDefinedChunker:
 
         if backend == "pallas":
             # fused device path: rows upload once; flags (small) come back for
-            # selection; the gather+fingerprint launch reuses the resident rows
+            # selection; the gather+fingerprint launches (CHUNKS_PER_LAUNCH
+            # chunks each, so device temp is bounded for any input size)
+            # reuse the resident rows
             import jax.numpy as jnp
             haloed, spans = pack_haloed(bufs)
             dev_rows = jnp.asarray(haloed)

@@ -7,9 +7,9 @@ cluster's multi-shard scatter probe all hold one of these.  It pairs
 
 * a **device-layout hash table** — the tiled bounded-window open-addressing
   layout of ``repro.kernels.fp_index``, two uint32 lane arrays probed
-  either by the Pallas kernel set (TPU, or interpret mode when forced) or
-  by a bit-identical vectorized numpy implementation (the CPU fast path) —
-  with
+  either by the Pallas kernel set (the TPU default; interpret mode when
+  forced on the CPU) or by a bit-identical vectorized numpy implementation
+  (the CPU default) — with
 * the **authoritative host state** — the index *is a* ``set`` of Python
   int fingerprints; the set is the ground truth the table accelerates.
 
@@ -39,9 +39,11 @@ into a journal — so bulk insertion costs what the plain host set costs,
 and the table build happens once, vectorized, at the next probe.  Batched
 probes (``contains_many``, ``probe_and_add``) are one vectorized launch
 per call, with ``*_async`` variants that split the launch from the
-consume so device probes overlap host work; tiny batches fall back to the
-host set, below the size where a vectorized launch wins (``small_batch``,
-set to 0 by tests that want the table path exercised unconditionally).
+consume so device probes overlap host work; tiny batches are answered by
+the host set, below the size where a vectorized launch wins
+(``small_batch``, set to 0 by tests that want the table path exercised
+unconditionally).  ``table_stats()`` counts the probed keys each path
+answered, so a run can show whether the device did the work.
 """
 
 from __future__ import annotations
@@ -54,11 +56,11 @@ from ..kernels.fp_index import (
     EMPTY32,
     OVERFLOW,
     PLACED_TOMB,
-    TILE_PAD,
     TOMB32,
     WINDOW,
     slot_hash_host,
     table_phys_len,
+    table_shape,
     tile_shape,
 )
 
@@ -101,6 +103,7 @@ class FingerprintIndex(set):
     __slots__ = (
         "_cap",
         "_tile_shift",
+        "_tile_pad",
         "_t64",
         "_dev_lo",
         "_dev_hi",
@@ -114,6 +117,8 @@ class FingerprintIndex(set):
         "_tombstones",
         "_backend",
         "small_batch",
+        "_probed_device",
+        "_probed_host",
     )
 
     def __init__(
@@ -129,6 +134,10 @@ class FingerprintIndex(set):
             raise ValueError(f"backend must be auto|numpy|pallas, got {backend!r}")
         self._backend = backend
         self.small_batch = small_batch
+        # probed keys answered by device launches / on the host (the
+        # small-batch set path or the numpy table)
+        self._probed_device = 0
+        self._probed_host = 0
         cap = 1
         while cap < capacity:
             cap <<= 1
@@ -137,12 +146,9 @@ class FingerprintIndex(set):
     # -- backend ---------------------------------------------------------------
     def _use_pallas(self) -> bool:
         if self._backend == "auto":
-            try:
-                import jax
+            from ..kernels.ops import device_platform
 
-                self._backend = "pallas" if jax.default_backend() == "tpu" else "numpy"
-            except Exception:
-                self._backend = "numpy"
+            self._backend = "pallas" if device_platform() == "tpu" else "numpy"
         return self._backend == "pallas"
 
     # -- device-buffer management ----------------------------------------------
@@ -152,9 +158,10 @@ class FingerprintIndex(set):
         if self._dev_lo is None:
             import jax.numpy as jnp
 
+            shape = table_shape(self._cap)
             tlo, thi = self._host_lanes()
-            self._dev_lo = jnp.asarray(tlo)
-            self._dev_hi = jnp.asarray(thi)
+            self._dev_lo = jnp.asarray(tlo.reshape(shape))
+            self._dev_hi = jnp.asarray(thi.reshape(shape))
         return self._dev_lo, self._dev_hi
 
     def _adopt_dev(self, tlo, thi) -> None:
@@ -202,8 +209,9 @@ class FingerprintIndex(set):
         while n_set > GROW_LOAD * cap:
             cap <<= 1
         self._cap = cap
-        _, tile_cap, _ = tile_shape(cap)
+        _, tile_cap, tile_phys = tile_shape(cap)
         self._tile_shift = tile_cap.bit_length() - 1
+        self._tile_pad = tile_phys - tile_cap
         # host table: the kernels' two uint32 lane arrays, interleaved into
         # one uint64 word per slot so the numpy fast path pays one gather
         # and one compare per probe round (``_lanes`` translates at the
@@ -287,11 +295,12 @@ class FingerprintIndex(set):
 
     def _phys_homes(self, keys: np.ndarray) -> np.ndarray:
         """Physical (flat) home slot per key: logical home mapped through
-        the tiled layout (each tile's row starts TILE_PAD slots later)."""
+        the tiled layout (each tile starts ``tile_phys - tile_cap`` slots
+        later than the one before)."""
         lo, hi = _split(keys)
         home = (slot_hash_host(lo, hi) & np.uint32(self._cap - 1)).astype(np.int64)
         if self._cap >> self._tile_shift > 1:
-            home += (home >> self._tile_shift) * TILE_PAD
+            home += (home >> self._tile_shift) * self._tile_pad
         return home
 
     def _table_insert(self, keys: np.ndarray) -> None:
@@ -304,7 +313,7 @@ class FingerprintIndex(set):
 
             lo, hi = _split(keys)
             tlo, thi = self._dev_tables()
-            tlo, thi, status = fp_index_insert(lo, hi, tlo, thi)
+            tlo, thi, status = fp_index_insert(lo, hi, tlo, thi, self._cap)
             self._adopt_dev(tlo, thi)
             over = status == OVERFLOW
             self._table_live += int(keys.size - over.sum())
@@ -351,7 +360,7 @@ class FingerprintIndex(set):
 
             lo, hi = _split(keys)
             tlo, thi = self._dev_tables()
-            tlo, thi, removed = fp_index_remove(lo, hi, tlo, thi)
+            tlo, thi, removed = fp_index_remove(lo, hi, tlo, thi, self._cap)
             self._adopt_dev(tlo, thi)
             hits = int(np.count_nonzero(removed))
             self._table_live -= hits
@@ -386,7 +395,7 @@ class FingerprintIndex(set):
             lo, hi = _split(keys)
             tlo, thi = self._dev_tables()
 
-            def consume(out=fp_index_probe(lo, hi, tlo, thi)):
+            def consume(out=fp_index_probe(lo, hi, tlo, thi, self._cap)):
                 return self._spill_fixup(keys, out)
 
             return consume
@@ -443,10 +452,15 @@ class FingerprintIndex(set):
             out = np.zeros(0, dtype=bool)
             return lambda: out
         if n <= self.small_batch:
+            self._probed_host += n
             out = np.fromiter(map(self.__contains__, keys.tolist()), dtype=bool, count=n)
             return lambda: out
         self._flush()
         consume = self._table_probe_launch(keys)
+        if self._backend == "pallas":
+            self._probed_device += n
+        else:
+            self._probed_host += n
         special = (keys == np.uint64(EMPTY_KEY)) | (keys == np.uint64(TOMB_KEY))
         if not special.any():
             return consume
@@ -518,6 +532,11 @@ class FingerprintIndex(set):
         if keys.size == 0:
             return
         super().update(keys.tolist())
+        if self._pending_removes:
+            # a re-added key whose tombstone is still pending sits in the
+            # table: the fold would find it there, then tombstone it
+            for k in keys.tolist():
+                self._pending_removes.pop(k, None)
         self._journal.append(keys.copy())
         self._journal_n += keys.size
 
@@ -643,6 +662,9 @@ class FingerprintIndex(set):
             "pending": len(self._pending_adds) + len(self._pending_removes) + self._journal_n,
             "backend": self._backend,
             "device_resident": self._dev_lo is not None,
+            "device_bytes": 0 if self._dev_lo is None else self._dev_lo.nbytes + self._dev_hi.nbytes,
+            "probed_device": self._probed_device,
+            "probed_host": self._probed_host,
         }
 
     def check_consistency(self) -> None:

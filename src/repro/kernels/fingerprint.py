@@ -65,49 +65,48 @@ def _avalanche(h: jnp.ndarray) -> jnp.ndarray:
     return h
 
 
-def _hash_tile(x: jnp.ndarray, w: int) -> jnp.ndarray:
-    """Hash a (tile_b, W) uint32 tile -> (tile_b, NUM_HASHES) uint32.
-
-    Shared by the kernel body and the jnp oracle (the *tiling*, not the math,
-    is what the kernel adds — see ref.py for an independently-written oracle).
-    """
-    tile_b = x.shape[0]
-    chunks = w // LANES
-    x3 = x.reshape(tile_b, chunks, LANES)
-
-    outs = []
-    for which in range(NUM_HASHES):
-        keys = _lane_keys(0xA5A5A5A5 + 0x01000193 * which)[None, :]
-        lane_mult = (
-            jnp.arange(LANES, dtype=jnp.uint32) * jnp.uint32(PRIME4) + jnp.uint32(SEEDS[which])
-        ) | jnp.uint32(1)
-        h = jnp.full((tile_b,), SEEDS[which], dtype=jnp.uint32)
-
-        def body(c, h, which=which, keys=keys, lane_mult=lane_mult):
-            chunk = x3[:, c, :]
-            t = (chunk ^ keys) * jnp.uint32(PRIME1)
-            t = t ^ jax.lax.shift_right_logical(t, jnp.uint32(15))
-            t = t * jnp.uint32(PRIME2)
-            # weighted lane reduction: order-sensitive within the chunk
-            s = jnp.sum(t * lane_mult[None, :], axis=1, dtype=jnp.uint32)
-            h = _rotl(h + s * jnp.uint32(PRIME3), 13) * jnp.uint32(PRIME1)
-            # fold the chunk index so chunk permutations change the digest
-            h = h ^ ((c.astype(jnp.uint32) + jnp.uint32(1)) * jnp.uint32(PRIME5))
-            return h
-
-        h = jax.lax.fori_loop(0, chunks, body, h)
-        h = h ^ jnp.uint32(w)  # length padding
-        outs.append(_avalanche(h))
-    return jnp.stack(outs, axis=1)
-
-
 def _rotl(v: jnp.ndarray, r: int) -> jnp.ndarray:
     r = jnp.uint32(r)
     return (v << r) | jax.lax.shift_right_logical(v, jnp.uint32(32) - r)
 
 
 def _fingerprint_kernel(x_ref, o_ref, *, w: int):
-    o_ref[...] = _hash_tile(x_ref[...], w)
+    """Hash one (TILE_B, W) uint32 tile -> (TILE_B, NUM_HASHES) uint32.
+
+    Each 128-lane chunk is read from the ref once (an aligned dynamic lane
+    slice) and feeds all four hashes.  The weighted lane reduction sums in
+    int32: Mosaic has no unsigned reductions, and a two's-complement sum
+    wraps exactly like the uint32 one, so the digests are unchanged.
+    """
+    tile_b = x_ref.shape[0]
+    lane = jnp.arange(LANES, dtype=jnp.uint32)
+    keys, lane_mults = [], []
+    for which in range(NUM_HASHES):
+        keys.append(_lane_keys(0xA5A5A5A5 + 0x01000193 * which)[None, :])
+        lane_mults.append(
+            ((lane * jnp.uint32(PRIME4) + jnp.uint32(SEEDS[which])) | jnp.uint32(1))[None, :]
+        )
+
+    def body(c, hs):
+        chunk = x_ref[:, pl.ds(pl.multiple_of(c * LANES, LANES), LANES)]
+        # fold the chunk index so chunk permutations change the digest
+        tag = (c.astype(jnp.uint32) + jnp.uint32(1)) * jnp.uint32(PRIME5)
+        out = []
+        for h, key, mult in zip(hs, keys, lane_mults):
+            t = (chunk ^ key) * jnp.uint32(PRIME1)
+            t = t ^ jax.lax.shift_right_logical(t, jnp.uint32(15))
+            t = t * jnp.uint32(PRIME2)
+            # weighted lane reduction: order-sensitive within the chunk
+            prod = jax.lax.bitcast_convert_type(t * mult, jnp.int32)
+            s = jax.lax.bitcast_convert_type(jnp.sum(prod, axis=1), jnp.uint32)
+            h = _rotl(h + s * jnp.uint32(PRIME3), 13) * jnp.uint32(PRIME1)
+            out.append(h ^ tag)
+        return tuple(out)
+
+    hs = tuple(jnp.full((tile_b,), SEEDS[which], dtype=jnp.uint32) for which in range(NUM_HASHES))
+    hs = jax.lax.fori_loop(0, w // LANES, body, hs)
+    # length padding, then the avalanche
+    o_ref[...] = jnp.stack([_avalanche(h ^ jnp.uint32(w)) for h in hs], axis=1)
 
 
 def fingerprint_pallas(blocks: jnp.ndarray, *, interpret: bool = False) -> jnp.ndarray:

@@ -12,23 +12,28 @@ hash table over **uint32 lanes**:
 * A key's home slot is a 32-bit avalanche hash of both words masked to the
   power-of-two *logical* capacity; collisions linear-probe a **bounded
   window** of ``WINDOW`` consecutive slots.
-* The logical slots are laid out in **tiles** of ``TILE_SLOTS`` slots, and
-  each tile carries ``TILE_PAD`` tail-pad slots past its logical end
-  (``TILE_PAD >= WINDOW - 1``), so a probe window is always contiguous
-  *within one tile* — no wraparound and no cross-tile windows in the
-  kernel's inner loop, one dynamic slice per key.  The physical arrays are
-  shaped ``(num_tiles, TILE_SLOTS + TILE_PAD)``; logical home slot ``h``
-  lives at row ``h // TILE_SLOTS``, column ``h % TILE_SLOTS``.
+* The logical slots are laid out in **tiles** of up to ``TILE_SLOTS``
+  slots.  A tile is stored as ``rows`` lane rows of 128 slots — the logical
+  slots rounded up to whole (8, 128) groups of ``GROUP_SLOTS``, plus one
+  more group of tail pad — so a probe window is always contiguous *within
+  one tile*, and the two aligned groups starting at the window's group
+  always hold it.  The device arrays are shaped ``(num_tiles, rows, 128)``;
+  logical home slot ``h`` lives in tile ``h // tile_cap`` at flat in-tile
+  position ``h % tile_cap`` (``phys_slots`` is the flat mapping the numpy
+  backend shares).
 * The grid runs **one table tile per grid row**: each grid step stages a
   single tile (not the whole table) in VMEM, so logical capacity is bounded
   by HBM, not VMEM.  The host wrapper routes each key to its home tile
-  (sort-by-tile + pad, see ``kernels.ops``); tiles are mutually
-  independent because windows never cross tile edges.
+  (sort-by-tile + pad, see ``kernels.ops``) and passes the per-tile key
+  counts as scalar prefetch, so the loop stops at each tile's last key;
+  tiles are mutually independent because windows never cross tile edges.
+* Keys and their in-tile home slots sit in SMEM, where the per-key loop
+  reads them as scalars.  Each key loads the two aligned (8, 128) groups
+  holding its window, masks the window's ``WINDOW`` lanes, and reduces.
 * ``EMPTY`` (all-zero) and ``TOMBSTONE`` (all-ones) are in-band sentinels;
   the host wrapper (``repro.core.fp_index``) routes the two colliding key
-  values — 0 and 2^64-1 — to its spill set, so the table itself never
-  stores them.  Key batches are padded to the tile grid with ``EMPTY``
-  keys, which every kernel skips (``valid`` guard).
+  values — 0 and 2^64-1 — to its spill set, so the table never stores them
+  and the kernels never see them.
 * **Probe** scans each key's whole window and reports a hit iff some slot
   holds both words — exact membership for every key the table holds, by
   construction (full 64-bit compare, not a partial-hash filter).
@@ -47,33 +52,33 @@ so steady-state launches ship **keys only** — the host wrapper keeps the
 returned device buffers for the next launch and materializes a host mirror
 only when the numpy path or a consistency check asks for one.
 
-Like the fingerprint/FFH kernels, all kernels run in interpret mode off
-TPU; the host wrapper's numpy backend implements the identical physical
-layout and window discipline, and tests/test_fp_index.py pins the two
-membership-equivalent against each other.
+The kernels compile for and run on TPU v5e; on the CPU (tests) they run in
+interpret mode.  The host wrapper's numpy backend implements the identical
+physical layout and window discipline, and tests/test_fp_index.py pins the
+two membership-equivalent against each other.
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # Bounded linear-probe window: every key lives within WINDOW slots of its
-# home slot or spills to the host.  16 lanes keeps the per-key dynamic
-# slice small while making overflow vanishingly rare below ~60% load.
+# home slot or spills to the host.  16 lanes keeps overflow vanishingly rare
+# below ~60% load.
 WINDOW = 16
 # Keys per grid step (second grid dimension tiles the key batch).
 TILE_KEYS = 1024
 # Logical slots per table tile: one grid step stages one tile in VMEM
-# (2 lane arrays x (TILE_SLOTS + TILE_PAD) x 4B ~ 260 KiB), so the table's
-# logical capacity is HBM-bound.
+# (2 lane arrays x (TILE_SLOTS + GROUP_SLOTS) x 4B ~ 264 KiB), so the
+# table's logical capacity is HBM-bound.
 TILE_SLOTS = 1 << 15
-# Per-tile tail pad.  Must be >= WINDOW - 1 (non-wrapping windows); 128
-# keeps every tile row a multiple of the TPU lane count.
-TILE_PAD = 128
+LANES = 128
+# One (8, 128) uint32 tile: the unit of aligned loads and of tile padding.
+GROUP_ROWS = 8
+GROUP_SLOTS = GROUP_ROWS * LANES
 
 # In-band slot sentinels (lo == hi == the value).
 EMPTY32 = 0
@@ -92,11 +97,20 @@ def tile_shape(cap: int):
     ``cap`` must be a power of two.  Tables at or below ``TILE_SLOTS`` are a
     single tile (``tile_cap == cap``); larger tables split into
     ``cap // TILE_SLOTS`` tiles of ``TILE_SLOTS`` logical slots each.
+    ``tile_phys`` is the tile's physical slot count: ``tile_cap`` rounded
+    up to whole groups, plus one group of tail pad.
     """
     if cap & (cap - 1):
         raise ValueError(f"logical capacity {cap} must be a power of two")
     tile_cap = min(cap, TILE_SLOTS)
-    return cap // tile_cap, tile_cap, tile_cap + TILE_PAD
+    groups = -(-tile_cap // GROUP_SLOTS) + 1
+    return cap // tile_cap, tile_cap, groups * GROUP_SLOTS
+
+
+def table_shape(cap: int):
+    """Device shape ``(num_tiles, rows, LANES)`` of each lane array."""
+    t, _, tile_phys = tile_shape(cap)
+    return t, tile_phys // LANES, LANES
 
 
 def table_phys_len(cap: int) -> int:
@@ -109,11 +123,11 @@ def phys_slots(home, cap: int):
     """Physical (flat) slot index of each logical home slot.
 
     The layout contract shared by the numpy backend and the kernels: tile
-    ``h // tile_cap`` starts ``TILE_PAD`` slots later per preceding tile.
-    Accepts and returns integer numpy arrays.
+    ``h // tile_cap`` starts ``tile_phys - tile_cap`` slots later per
+    preceding tile.  Accepts and returns integer numpy arrays.
     """
-    _, tile_cap, _ = tile_shape(cap)
-    return home + (home // tile_cap) * TILE_PAD
+    _, tile_cap, tile_phys = tile_shape(cap)
+    return home + (home // tile_cap) * (tile_phys - tile_cap)
 
 
 def slot_hash_host(lo, hi):
@@ -142,84 +156,124 @@ def _slot_hash_jnp(lo: jnp.ndarray, hi: jnp.ndarray) -> jnp.ndarray:
     return x ^ jax.lax.shift_right_logical(x, jnp.uint32(16))
 
 
-def _check_tiled(keys_lo, table_lo):
-    t, k = keys_lo.shape
-    tt, tile_phys = table_lo.shape
-    tile_cap = tile_phys - TILE_PAD
-    if t != tt:
-        raise ValueError(f"key rows {t} != table tiles {tt}")
-    if tile_cap <= 0 or tile_cap & (tile_cap - 1):
-        raise ValueError(f"tile capacity {tile_cap} must be a positive power of two")
-    if k % TILE_KEYS:
-        raise ValueError(f"keys per tile {k} must be a multiple of TILE_KEYS={TILE_KEYS}")
-    return t, k, tile_cap, tile_phys
+def _check_tiled(counts, keys_lo, table_lo, cap: int):
+    t, rows, lanes = table_shape(cap)
+    if table_lo.shape != (t, rows, lanes):
+        raise ValueError(f"table shape {table_lo.shape} != {(t, rows, lanes)} for capacity {cap}")
+    if counts.shape != (t,):
+        raise ValueError(f"counts shape {counts.shape} != ({t},)")
+    (n,) = keys_lo.shape
+    k = n // t
+    if k * t != n or k % TILE_KEYS:
+        raise ValueError(f"keys per tile ({n}/{t}) must be a multiple of TILE_KEYS={TILE_KEYS}")
+    return t, k, rows
 
 
-def _probe_kernel(klo_ref, khi_ref, tlo_ref, thi_ref, out_ref, *, tile_mask: int):
-    """Batched membership probe: one contiguous WINDOW load per key.
+def _window_iota():
+    """Flat slot offset of every lane of a two-group window load."""
+    shape = (2 * GROUP_ROWS, LANES)
+    return (jax.lax.broadcasted_iota(jnp.int32, shape, 0) * LANES
+            + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
 
-    The key's in-tile home is its global home masked to the tile capacity
-    (tile capacities divide the global capacity, both powers of two); the
-    host routed the key to this tile, so only the low bits matter here.
-    """
-    klo = klo_ref[0, :]
-    khi = khi_ref[0, :]
-    n = klo.shape[0]
-    slots = _slot_hash_jnp(klo, khi) & jnp.uint32(tile_mask)
 
-    def body(i, _):
-        slot = slots[i].astype(jnp.int32)
-        wlo = tlo_ref[0, pl.ds(slot, WINDOW)]
-        whi = thi_ref[0, pl.ds(slot, WINDOW)]
-        hit = jnp.any((wlo == klo[i]) & (whi == khi[i]))
-        out_ref[0, pl.ds(i, 1)] = hit.astype(jnp.int32)[None]
-        return 0
+def _window(slot):
+    """``(row0, rel)`` for an in-tile home slot: the window lives in the two
+    aligned groups starting at row ``row0``; ``rel`` is each loaded lane's
+    offset from the home slot (the window is ``0 <= rel < WINDOW``)."""
+    row0 = pl.multiple_of((slot // GROUP_SLOTS) * GROUP_ROWS, GROUP_ROWS)
+    return row0, slot % GROUP_SLOTS
 
-    jax.lax.fori_loop(0, n, body, 0)
+
+def _tile_keys(cnt_ref):
+    """Number of real keys in this grid step's key block."""
+    start = pl.program_id(1) * TILE_KEYS
+    return jnp.clip(cnt_ref[pl.program_id(0)] - start, 0, TILE_KEYS)
+
+
+def _key_spec(k: int):
+    """One key block of tile ``i``'s ``k``-key segment, in SMEM."""
+    kb = k // TILE_KEYS
+    return pl.BlockSpec((TILE_KEYS,), lambda i, j, cnt: (i * kb + j,), memory_space=pltpu.SMEM)
+
+
+def _table_spec(rows: int):
+    return pl.BlockSpec((1, rows, LANES), lambda i, j, cnt: (i, 0, 0))
+
+
+def _in_tile_slots(keys_lo, keys_hi, tile_cap: int):
+    return (_slot_hash_jnp(keys_lo, keys_hi) & jnp.uint32(tile_cap - 1)).astype(jnp.int32)
+
+
+def _probe_kernel(cnt_ref, klo_ref, khi_ref, slot_ref, tlo_ref, thi_ref, out_ref):
+    """Batched membership probe: one aligned two-group load per key."""
+    iota = _window_iota()
+
+    def body(i, carry):
+        row0, off = _window(slot_ref[i])
+        rel = iota - off
+        wlo = tlo_ref[0, pl.ds(row0, 2 * GROUP_ROWS), :]
+        whi = thi_ref[0, pl.ds(row0, 2 * GROUP_ROWS), :]
+        hit = (rel >= 0) & (rel < WINDOW) & (wlo == klo_ref[i]) & (whi == khi_ref[i])
+        out_ref[i] = jnp.max(hit.astype(jnp.int32))
+        return carry
+
+    jax.lax.fori_loop(0, _tile_keys(cnt_ref), body, 0)
 
 
 def fp_probe_pallas(
+    counts: jnp.ndarray,
     keys_lo: jnp.ndarray,
     keys_hi: jnp.ndarray,
     table_lo: jnp.ndarray,
     table_hi: jnp.ndarray,
     *,
+    cap: int,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """(T, K) int32 membership flags for tile-routed split keys.
+    """(T*K,) int32 membership flags for tile-routed split keys.
 
-    ``keys_*`` are ``(T, K)`` — row ``t`` holds the keys whose home slot
-    lives in table tile ``t``, EMPTY-padded to ``K`` (a multiple of
-    TILE_KEYS).  ``table_*`` are the physical ``(T, tile_cap + TILE_PAD)``
-    lane arrays.  Pad-key flags are garbage (an EMPTY key "matches" any
-    empty slot); the caller slices them off.
+    ``keys_*`` are flat ``(T*K,)``: segment ``t`` holds the keys whose home
+    slot lives in table tile ``t``, padded to ``K`` (a multiple of
+    TILE_KEYS); ``counts[t]`` says how many are real.  ``table_*`` are the
+    ``table_shape(cap)`` lane arrays.  Flags past each tile's count are
+    undefined; the caller drops them.
     """
-    t, k, tile_cap, tile_phys = _check_tiled(keys_lo, table_lo)
-    grid = (t, k // TILE_KEYS)
+    t, k, rows = _check_tiled(counts, keys_lo, table_lo, cap)
+    slots = _in_tile_slots(keys_lo, keys_hi, tile_shape(cap)[1])
+    key = _key_spec(k)
     return pl.pallas_call(
-        functools.partial(_probe_kernel, tile_mask=tile_cap - 1),
-        out_shape=jax.ShapeDtypeStruct((t, k), jnp.int32),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, TILE_KEYS), lambda i, j: (i, j)),
-            pl.BlockSpec((1, TILE_KEYS), lambda i, j: (i, j)),
-            pl.BlockSpec((1, tile_phys), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, tile_phys), lambda i, j: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, TILE_KEYS), lambda i, j: (i, j)),
+        _probe_kernel,
+        out_shape=jax.ShapeDtypeStruct((t * k,), jnp.int32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(t, k // TILE_KEYS),
+            in_specs=[key, key, key, _table_spec(rows), _table_spec(rows)],
+            out_specs=key,
+        ),
         interpret=interpret,
-    )(keys_lo, keys_hi, table_lo, table_hi)
+    )(counts, keys_lo, keys_hi, slots, table_lo, table_hi)
 
 
 # Insert statuses.
 PLACED = 0  # consumed an EMPTY slot
-PRESENT = 1  # key already in its window (pad keys also report PRESENT)
+PRESENT = 1  # key already in its window
 OVERFLOW = 2  # window full -> host spill
 PLACED_TOMB = 3  # consumed a TOMBSTONE slot
 
 
+def _copy_tile_in(tlo_in_ref, thi_in_ref, tlo_ref, thi_ref):
+    """Stage the tile into the aliased output block on its first key block:
+    output blocks are never loaded from HBM, and every tile is written back
+    whole, keyed or not."""
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        tlo_ref[...] = tlo_in_ref[...]
+        thi_ref[...] = thi_in_ref[...]
+
+
 def _insert_kernel(
-    klo_ref, khi_ref, tlo_in_ref, thi_in_ref, tlo_ref, thi_ref, status_ref, *, tile_mask: int
+    cnt_ref, klo_ref, khi_ref, slot_ref, tlo_in_ref, thi_in_ref, tlo_ref, thi_ref, status_ref
 ):
     """Sequential batched insert: first-fit within each key's window.
 
@@ -228,159 +282,118 @@ def _insert_kernel(
     so a key inserted earlier in the batch is visible (as PRESENT) to later
     duplicates and two keys sharing a window never claim the same slot.
     ``tlo_ref``/``thi_ref`` alias the input table buffers (in-place update);
-    all reads and writes go through the output refs.
+    after the first key block stages the tile, all reads and writes go
+    through the output refs.
     """
-    del tlo_in_ref, thi_in_ref  # aliased with tlo_ref/thi_ref
-    klo = klo_ref[0, :]
-    khi = khi_ref[0, :]
-    n = klo.shape[0]
-    slots = _slot_hash_jnp(klo, khi) & jnp.uint32(tile_mask)
+    _copy_tile_in(tlo_in_ref, thi_in_ref, tlo_ref, thi_ref)
+    iota = _window_iota()
 
-    def body(i, _):
-        kl = klo[i]
-        kh = khi[i]
-        valid = jnp.logical_not((kl == jnp.uint32(EMPTY32)) & (kh == jnp.uint32(EMPTY32)))
-        slot = slots[i].astype(jnp.int32)
-        wlo = tlo_ref[0, pl.ds(slot, WINDOW)]
-        whi = thi_ref[0, pl.ds(slot, WINDOW)]
-        match = (wlo == kl) & (whi == kh)
-        empty = (wlo == jnp.uint32(EMPTY32)) & (whi == jnp.uint32(EMPTY32))
-        tomb = (wlo == jnp.uint32(TOMB32)) & (whi == jnp.uint32(TOMB32))
-        present = jnp.any(match)
-        # first free lane, and whether it is a tombstone (argmax of a bool
-        # mask is its first True; WINDOW = "none")
-        first_empty = jnp.where(jnp.any(empty), jnp.argmax(empty), WINDOW).astype(jnp.int32)
-        first_tomb = jnp.where(jnp.any(tomb), jnp.argmax(tomb), WINDOW).astype(jnp.int32)
-        off = jnp.minimum(first_empty, first_tomb)
-        has_free = off < WINDOW
-        took_tomb = first_tomb < first_empty
+    def body(i, carry):
+        kl = klo_ref[i]
+        kh = khi_ref[i]
+        row0, off = _window(slot_ref[i])
+        rel = iota - off
+        rows = pl.ds(row0, 2 * GROUP_ROWS)
+        wlo = tlo_ref[0, rows, :]
+        whi = thi_ref[0, rows, :]
+        inwin = (rel >= 0) & (rel < WINDOW)
+        present = jnp.max((inwin & (wlo == kl) & (whi == kh)).astype(jnp.int32)) > 0
+        empty = inwin & (wlo == jnp.uint32(EMPTY32)) & (whi == jnp.uint32(EMPTY32))
+        tomb = inwin & (wlo == jnp.uint32(TOMB32)) & (whi == jnp.uint32(TOMB32))
+        # first free lane of each kind (WINDOW = "none")
+        first_empty = jnp.min(jnp.where(empty, rel, WINDOW))
+        first_tomb = jnp.min(jnp.where(tomb, rel, WINDOW))
+        target = jnp.minimum(first_empty, first_tomb)
+        has_free = target < WINDOW
 
-        @pl.when(valid & jnp.logical_not(present) & has_free)
+        @pl.when(jnp.logical_not(present) & has_free)
         def _place():
-            tlo_ref[0, pl.ds(slot + off, 1)] = kl[None]
-            thi_ref[0, pl.ds(slot + off, 1)] = kh[None]
+            at = rel == target
+            tlo_ref[0, rows, :] = jnp.where(at, kl, wlo)
+            thi_ref[0, rows, :] = jnp.where(at, kh, whi)
 
-        status_ref[0, pl.ds(i, 1)] = jnp.where(
-            jnp.logical_not(valid) | present,
+        status_ref[i] = jnp.where(
+            present,
             jnp.int32(PRESENT),
             jnp.where(
                 has_free,
-                jnp.where(took_tomb, jnp.int32(PLACED_TOMB), jnp.int32(PLACED)),
+                jnp.where(first_tomb < first_empty, jnp.int32(PLACED_TOMB), jnp.int32(PLACED)),
                 jnp.int32(OVERFLOW),
             ),
-        )[None]
-        return 0
+        )
+        return carry
 
-    jax.lax.fori_loop(0, n, body, 0)
+    jax.lax.fori_loop(0, _tile_keys(cnt_ref), body, 0)
 
 
-def fp_insert_pallas(
-    keys_lo: jnp.ndarray,
-    keys_hi: jnp.ndarray,
-    table_lo: jnp.ndarray,
-    table_hi: jnp.ndarray,
-    *,
-    interpret: bool = False,
-):
+def _mutate_call(kernel, counts, keys_lo, keys_hi, table_lo, table_hi, cap, interpret):
+    t, k, rows = _check_tiled(counts, keys_lo, table_lo, cap)
+    slots = _in_tile_slots(keys_lo, keys_hi, tile_shape(cap)[1])
+    key = _key_spec(k)
+    return pl.pallas_call(
+        kernel,
+        out_shape=[
+            jax.ShapeDtypeStruct(table_lo.shape, jnp.uint32),
+            jax.ShapeDtypeStruct(table_hi.shape, jnp.uint32),
+            jax.ShapeDtypeStruct((t * k,), jnp.int32),
+        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(t, k // TILE_KEYS),
+            in_specs=[key, key, key, _table_spec(rows), _table_spec(rows)],
+            out_specs=[_table_spec(rows), _table_spec(rows), key],
+        ),
+        # operand indices count the scalar-prefetch counts (operand 0)
+        input_output_aliases={4: 0, 5: 1},
+        interpret=interpret,
+    )(counts, keys_lo, keys_hi, slots, table_lo, table_hi)
+
+
+def fp_insert_pallas(counts, keys_lo, keys_hi, table_lo, table_hi, *, cap: int,
+                     interpret: bool = False):
     """Insert tile-routed split keys; returns ``(table_lo, table_hi, status)``.
 
     Same key/table layout as ``fp_probe_pallas``.  The table arrays are
     updated in place on device (input/output aliasing) — steady-state
-    launches transfer keys only.  EMPTY pad keys are skipped (status
-    PRESENT).
+    launches transfer keys only.
     """
-    t, k, tile_cap, tile_phys = _check_tiled(keys_lo, table_lo)
-    grid = (t, k // TILE_KEYS)
-    return pl.pallas_call(
-        functools.partial(_insert_kernel, tile_mask=tile_cap - 1),
-        out_shape=[
-            jax.ShapeDtypeStruct((t, tile_phys), jnp.uint32),
-            jax.ShapeDtypeStruct((t, tile_phys), jnp.uint32),
-            jax.ShapeDtypeStruct((t, k), jnp.int32),
-        ],
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, TILE_KEYS), lambda i, j: (i, j)),
-            pl.BlockSpec((1, TILE_KEYS), lambda i, j: (i, j)),
-            pl.BlockSpec((1, tile_phys), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, tile_phys), lambda i, j: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, tile_phys), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, tile_phys), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, TILE_KEYS), lambda i, j: (i, j)),
-        ],
-        input_output_aliases={2: 0, 3: 1},
-        interpret=interpret,
-    )(keys_lo, keys_hi, table_lo, table_hi)
+    return _mutate_call(_insert_kernel, counts, keys_lo, keys_hi, table_lo, table_hi, cap,
+                        interpret)
 
 
 def _remove_kernel(
-    klo_ref, khi_ref, tlo_in_ref, thi_in_ref, tlo_ref, thi_ref, status_ref, *, tile_mask: int
+    cnt_ref, klo_ref, khi_ref, slot_ref, tlo_in_ref, thi_in_ref, tlo_ref, thi_ref, status_ref
 ):
     """Tombstone the matching slot of each (resident) key."""
-    del tlo_in_ref, thi_in_ref  # aliased with tlo_ref/thi_ref
-    klo = klo_ref[0, :]
-    khi = khi_ref[0, :]
-    n = klo.shape[0]
-    slots = _slot_hash_jnp(klo, khi) & jnp.uint32(tile_mask)
+    _copy_tile_in(tlo_in_ref, thi_in_ref, tlo_ref, thi_ref)
+    iota = _window_iota()
 
-    def body(i, _):
-        kl = klo[i]
-        kh = khi[i]
-        valid = jnp.logical_not((kl == jnp.uint32(EMPTY32)) & (kh == jnp.uint32(EMPTY32)))
-        slot = slots[i].astype(jnp.int32)
-        wlo = tlo_ref[0, pl.ds(slot, WINDOW)]
-        whi = thi_ref[0, pl.ds(slot, WINDOW)]
-        match = (wlo == kl) & (whi == kh)
-        found = jnp.any(match)
-        off = jnp.argmax(match).astype(jnp.int32)
+    def body(i, carry):
+        row0, off = _window(slot_ref[i])
+        rel = iota - off
+        rows = pl.ds(row0, 2 * GROUP_ROWS)
+        wlo = tlo_ref[0, rows, :]
+        whi = thi_ref[0, rows, :]
+        match = (rel >= 0) & (rel < WINDOW) & (wlo == klo_ref[i]) & (whi == khi_ref[i])
+        found = jnp.max(match.astype(jnp.int32))
 
-        @pl.when(valid & found)
+        @pl.when(found > 0)
         def _tombstone():
-            tlo_ref[0, pl.ds(slot + off, 1)] = jnp.uint32(TOMB32)[None]
-            thi_ref[0, pl.ds(slot + off, 1)] = jnp.uint32(TOMB32)[None]
+            tlo_ref[0, rows, :] = jnp.where(match, jnp.uint32(TOMB32), wlo)
+            thi_ref[0, rows, :] = jnp.where(match, jnp.uint32(TOMB32), whi)
 
-        status_ref[0, pl.ds(i, 1)] = (valid & found).astype(jnp.int32)[None]
-        return 0
+        status_ref[i] = found
+        return carry
 
-    jax.lax.fori_loop(0, n, body, 0)
+    jax.lax.fori_loop(0, _tile_keys(cnt_ref), body, 0)
 
 
-def fp_remove_pallas(
-    keys_lo: jnp.ndarray,
-    keys_hi: jnp.ndarray,
-    table_lo: jnp.ndarray,
-    table_hi: jnp.ndarray,
-    *,
-    interpret: bool = False,
-):
+def fp_remove_pallas(counts, keys_lo, keys_hi, table_lo, table_hi, *, cap: int,
+                     interpret: bool = False):
     """Remove tile-routed split keys; returns ``(table_lo, table_hi, status)``.
 
-    ``status`` is 1 where a slot was tombstoned, 0 otherwise (pad keys and
-    misses).  In-place on device, keys-only transfer, like insert.
+    ``status`` is 1 where a slot was tombstoned, 0 on a miss.  In-place on
+    device, keys-only transfer, like insert.
     """
-    t, k, tile_cap, tile_phys = _check_tiled(keys_lo, table_lo)
-    grid = (t, k // TILE_KEYS)
-    return pl.pallas_call(
-        functools.partial(_remove_kernel, tile_mask=tile_cap - 1),
-        out_shape=[
-            jax.ShapeDtypeStruct((t, tile_phys), jnp.uint32),
-            jax.ShapeDtypeStruct((t, tile_phys), jnp.uint32),
-            jax.ShapeDtypeStruct((t, k), jnp.int32),
-        ],
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, TILE_KEYS), lambda i, j: (i, j)),
-            pl.BlockSpec((1, TILE_KEYS), lambda i, j: (i, j)),
-            pl.BlockSpec((1, tile_phys), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, tile_phys), lambda i, j: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, tile_phys), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, tile_phys), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, TILE_KEYS), lambda i, j: (i, j)),
-        ],
-        input_output_aliases={2: 0, 3: 1},
-        interpret=interpret,
-    )(keys_lo, keys_hi, table_lo, table_hi)
+    return _mutate_call(_remove_kernel, counts, keys_lo, keys_hi, table_lo, table_hi, cap,
+                        interpret)

@@ -8,8 +8,7 @@ and only launch/dryrun.py is allowed to request 512 host devices.
 from __future__ import annotations
 
 import jax
-
-from repro.jax_compat import auto_axis_types, make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -20,14 +19,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=auto_axis_types(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model_axis: int = 1):
     """Tiny mesh over whatever devices exist (tests / smoke runs)."""
     n = len(jax.devices())
     data = n // model_axis
-    return make_mesh(
+    return jax.make_mesh(
         (data, model_axis), ("data", "model"),
-        axis_types=auto_axis_types(2),
+        axis_types=(AxisType.Auto,) * 2,
     )

@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.act_sharding import active_mesh, batch_mesh_axes
-from repro.jax_compat import shard_map
 
 from .layers import ParamFactory
 
@@ -161,7 +160,7 @@ def moe_apply(
         )
         return out.reshape(bl, sl, d), aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         mapped,
         mesh=mesh,
         in_specs=(xspec, P(None, None), wspec, (wspec if w_gate is not None else P()), wspec_out),

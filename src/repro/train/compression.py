@@ -23,8 +23,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.jax_compat import axis_size
-
 
 def _quantize(x: jnp.ndarray, chunk: int = 256) -> Tuple[jnp.ndarray, jnp.ndarray]:
     flat = x.reshape(-1)
@@ -48,7 +46,7 @@ def compressed_psum_mean(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
     is all_to_all (int8 + f32 scales) -> local sum -> all_gather (int8), so
     every hop carries ~1/4 of the fp32 bytes.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     shape, size = x.shape, x.size
     pad = (-size) % (n * 256)
     flat = jnp.pad(x.reshape(-1), (0, pad))

@@ -219,16 +219,17 @@ def test_kernel_probe_against_numpy_table():
     idx.add_many(ks)
     idx.contains_many(ks)  # flush pending into the table
     tlo, thi = idx._lanes()
+    cap = idx.table_stats()["capacity"]
     lo = (ks & np.uint64(0xFFFFFFFF)).astype(np.uint32)
     hi = (ks >> np.uint64(32)).astype(np.uint32)
-    got = fp_index_probe(lo, hi, tlo, thi)
+    got = fp_index_probe(lo, hi, tlo, thi, cap)
     np.testing.assert_array_equal(got, np.ones(ks.size, bool))
     absent = np.setdiff1d(_keys(rng, 300), ks)
     alo = (absent & np.uint64(0xFFFFFFFF)).astype(np.uint32)
     ahi = (absent >> np.uint64(32)).astype(np.uint32)
-    assert not fp_index_probe(alo, ahi, tlo, thi).any()
+    assert not fp_index_probe(alo, ahi, tlo, thi, cap).any()
     # kernel insert into the numpy-built table: duplicates are PRESENT
-    _, _, status = fp_index_insert(lo[:32], hi[:32], tlo.copy(), thi.copy())
+    _, _, status = fp_index_insert(lo[:32], hi[:32], tlo.copy(), thi.copy(), cap)
     assert (status == 1).all()
 
 
@@ -397,4 +398,19 @@ def test_grow_during_probe_and_add(backend):
     assert set(idx) == oracle
     # every key (pre-grow residents and post-grow inserts) probes present
     np.testing.assert_array_equal(idx.contains_many(batch), np.ones(batch.size, bool))
+    idx.check_consistency()
+
+
+@pytest.mark.parametrize("backend", ["numpy", "pallas"])
+def test_add_many_after_pending_discard_stays_resident(backend):
+    """A table-resident key discarded (tombstone pending) and then re-added
+    by ``add_many`` before any flush must stay a member: the fold finds it
+    still in the table, so its pending tombstone has to be dropped."""
+    idx = FingerprintIndex(capacity=32, small_batch=0, backend=backend)
+    idx.add(1)
+    idx.remove_many(np.array([0], dtype=np.uint64))  # flushes: 1 reaches the table
+    idx.discard(1)
+    idx.add_many(np.array([1], dtype=np.uint64))
+    np.testing.assert_array_equal(idx.contains_many(np.array([1], dtype=np.uint64)), [True])
+    assert set(idx) == {1}
     idx.check_consistency()

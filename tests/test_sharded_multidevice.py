@@ -26,10 +26,10 @@ def test_moe_shardmap_matches_local():
     _run("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.act_sharding import activation_rules
-        from repro.jax_compat import auto_axis_types, make_mesh
+        from jax.sharding import AxisType
         from repro.models.moe import init_moe, moe_apply
         from repro.models.layers import ParamFactory, unzip_params
-        mesh = make_mesh((4,2), ("data","model"), axis_types=auto_axis_types(2))
+        mesh = jax.make_mesh((4,2), ("data","model"), axis_types=(AxisType.Auto,) * 2)
         for E in (4, 3):
             pf = ParamFactory(jax.random.PRNGKey(0), jnp.float32)
             params, _ = unzip_params(init_moe(pf, 16, 32, E, "swiglu"))
@@ -46,13 +46,13 @@ def test_compressed_psum_close_to_exact():
     _run("""
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.jax_compat import auto_axis_types, make_mesh, shard_map
+        from jax.sharding import AxisType
         from repro.train.compression import compressed_psum_mean
-        mesh = make_mesh((8,), ("data",), axis_types=auto_axis_types(1))
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
         x = jnp.asarray(np.random.default_rng(0).standard_normal((8, 1000)), jnp.float32)
         exact = jnp.mean(x, axis=0)
-        f = shard_map(lambda xs: compressed_psum_mean(xs[0], "data"),
-                      mesh=mesh, in_specs=P("data", None), out_specs=P(None), check_vma=False)
+        f = jax.shard_map(lambda xs: compressed_psum_mean(xs[0], "data"),
+                          mesh=mesh, in_specs=P("data", None), out_specs=P(None), check_vma=False)
         approx = jax.jit(f)(x)
         err = float(jnp.max(jnp.abs(approx - exact)))
         rng = float(jnp.max(jnp.abs(exact)) )
@@ -66,9 +66,9 @@ def test_elastic_restore_across_mesh_shapes():
         import tempfile, numpy as np, jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.ckpt import checkpoint as ckpt
-        from repro.jax_compat import auto_axis_types, make_mesh
-        m1 = make_mesh((4,2), ("data","model"), axis_types=auto_axis_types(2))
-        m2 = make_mesh((2,4), ("data","model"), axis_types=auto_axis_types(2))
+        from jax.sharding import AxisType
+        m1 = jax.make_mesh((4,2), ("data","model"), axis_types=(AxisType.Auto,) * 2)
+        m2 = jax.make_mesh((2,4), ("data","model"), axis_types=(AxisType.Auto,) * 2)
         w = jnp.arange(64.0).reshape(8, 8)
         w1 = jax.device_put(w, NamedSharding(m1, P("data", "model")))
         with tempfile.TemporaryDirectory() as d:
@@ -100,8 +100,8 @@ def test_sharded_train_step_matches_single_device():
         step = make_train_step(model, opt)
         _, _, loss_ref, _ = jax.jit(step)(params, opt.init(params), batch)
 
-        from repro.jax_compat import auto_axis_types, make_mesh
-        mesh = make_mesh((4,2), ("data","model"), axis_types=auto_axis_types(2))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((4,2), ("data","model"), axis_types=(AxisType.Auto,) * 2)
         sds, axes = model.abstract_params()
         pspecs = param_pspecs(sds, axes, mesh, mode="train", fsdp=True)
         bspecs = batch_pspecs(cfg, "train", 8, mesh)
