@@ -47,6 +47,7 @@ from typing import Optional
 
 import numpy as np
 
+from .. import obs
 from .fingerprint import OP_WRITE, TRACE_DTYPE
 from .inline_engine import _PendingRun
 from .reservoir import Reservoir
@@ -346,14 +347,25 @@ def _certify_staged(store, w_streams: np.ndarray, w_lbas: np.ndarray, pending_ke
 
 
 def _hpdedup_bulk(hp, rb: ReplayBatch, out: Optional[np.ndarray], base: int) -> None:
-    """Vectorized pre-pass + residual loop for a boundary-free record span.
+    """Vectorized pre-pass + residual loop for a boundary-free record span
+    (one ``engine.prepass`` and one ``engine.decide`` profiler span).
 
     Caller guarantees no estimator-interval or postprocess-period trigger
     fires for any write in ``rb``.
     """
-    n = len(rb)
-    if n == 0:
+    if len(rb) == 0:
         return
+    with obs.span("engine.prepass", keys=len(rb)):
+        is_w, maybe_w, staged = _hpdedup_prepass(hp, rb)
+    with obs.span("engine.decide", keys=len(rb)):
+        _hpdedup_decide(hp, rb, out, base, is_w, maybe_w, staged)
+
+
+def _hpdedup_prepass(hp, rb: ReplayBatch):
+    """Both index probes, the staged-store certificate, per-stream
+    accumulation and estimator offers; returns ``(is_w, maybe_w, staged)``
+    for the residual loop."""
+    n = len(rb)
     inline = hp.inline
     m = inline.metrics
     thr = inline.thresholds
@@ -437,8 +449,17 @@ def _hpdedup_bulk(hp, rb: ReplayBatch, out: Optional[np.ndarray], base: int) -> 
     m.reads += nr
     hp._total_writes += nw
     hp._writes_since_post += nw
+    return is_w, maybe_w, staged
 
-    # ---- residual loop: run decisions, admissions/evictions, store I/O ----
+
+def _hpdedup_decide(hp, rb: ReplayBatch, out: Optional[np.ndarray], base: int, is_w,
+                    maybe_w: Optional[np.ndarray], staged: bool) -> None:
+    """The residual loop: run decisions, admissions/evictions, store I/O."""
+    n = len(rb)
+    inline = hp.inline
+    m = inline.metrics
+    thr = inline.thresholds
+    store = inline.store
     streams_l = rb.stream.tolist()
     lbas_l = rb.lba.tolist()
     fps_l = rb.fp.tolist()
@@ -633,13 +654,10 @@ def hpdedup_run(hp, rb: ReplayBatch, out: Optional[np.ndarray] = None) -> None:
     pos = 0
     wptr = 0  # index into w_pos of the first write at/after pos
     while pos < n:
-        k = None  # writes until (and including) the next trigger
-        if est is not None:
-            k = est.interval_len - est.writes_in_interval
-        if period:
-            k_post = period - hp._writes_since_post
-            if k is None or k_post < k:
-                k = k_post
+        # writes until (and including) the next estimator / postprocess trigger
+        k_est = est.interval_len - est.writes_in_interval if est is not None else None
+        k_post = period - hp._writes_since_post if period else None
+        k = k_est if k_post is None or (k_est is not None and k_est <= k_post) else k_post
         if k is not None and k < 1:
             k = 1  # trigger already due: next write must replay scalarly
         if k is None:
@@ -655,7 +673,11 @@ def hpdedup_run(hp, rb: ReplayBatch, out: Optional[np.ndarray] = None) -> None:
         if boundary is None:
             break
         # the trigger-carrying record replays through the scalar oracle path
-        deduped = hp.write(int(rb.stream[boundary]), int(rb.lba[boundary]), int(rb.fp[boundary]))
+        kind = (obs.BOUNDARY_INTERVAL if k_est is not None and k_est <= k else 0) | (
+            obs.BOUNDARY_POST if k_post is not None and k_post <= k else 0)
+        with obs.span("engine.boundary", kind=kind):
+            deduped = hp.write(int(rb.stream[boundary]), int(rb.lba[boundary]),
+                               int(rb.fp[boundary]))
         if out is not None and deduped:
             out[boundary] = True
         if w_pos is not None:

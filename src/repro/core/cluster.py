@@ -76,6 +76,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from .. import obs
 from .batch_replay import (
     DEFAULT_BATCH_SIZE,
     ReplayBatch,
@@ -498,6 +499,8 @@ class ShardedCluster:
         # than the work on tiny sub-batches; measured 0.41x on a 1-CPU host
         # under fingerprint routing).  Plain attribute, not serialized.
         self.min_parallel_batch = 2048
+        # write_batch calls so far: the ``batch`` stat of its profiler spans
+        self.write_batches = 0
         # coordinator mutual exclusion (see _locked) + executor fault state:
         # shards whose worker raised are poisoned until fail/recover or a
         # snapshot reload re-establishes their state
@@ -1001,41 +1004,58 @@ class ShardedCluster:
         thread and the flags are gathered after the barrier — per-shard
         record sequences are identical to the serial path, so the flags (and
         all engine state) are bit-exact.  Records routed to a failed shard
-        are logged for recovery but not executed; their flags read False."""
+        are logged for recovery but not executed; their flags read False.
+
+        Each call is one ``cluster.write_batch`` profiler span numbered by
+        ``write_batches``; each shard's sub-batch is a ``shard.write_batch``
+        span with the same ``batch``, on the thread that runs it."""
         self._check_poisoned()
         rb = ReplayBatch(np.asarray(streams), np.asarray(lbas), np.asarray(fps))
-        sid = self._route_chunk(rb)
+        batch = self.write_batches
+        self.write_batches = batch + 1
+        with obs.span("cluster.write_batch", batch=batch, keys=len(rb)):
+            return self._write_batch(rb, batch)
+
+    def _write_batch(self, rb: ReplayBatch, batch: int) -> np.ndarray:
+        with obs.span("cluster.route"):
+            sid = self._route_chunk(rb)
         out = np.zeros(len(rb), dtype=bool)
-        parts, order = rb.scatter(sid, self.num_shards)
+        with obs.span("cluster.scatter"):
+            parts, order = rb.scatter(sid, self.num_shards)
         ex = self._executor
         largest = max((len(sub) for sub in parts if sub is not None), default=0)
         if ex is None or self.num_shards == 1 or largest < self.min_parallel_batch:
             if ex is not None and self._workers_dirty:
-                self._sync()
+                with obs.span("cluster.wait"):
+                    self._sync()
             flags = []
             for s, sub in enumerate(parts):
                 if sub is not None:
                     if s in self._failed:
                         flags.append(np.zeros(len(sub), dtype=bool))
                     else:
-                        flags.append(self.shards[s].write_batch(sub.stream, sub.lba, sub.fp))
+                        with obs.span("shard.write_batch", shard=s, batch=batch, keys=len(sub)):
+                            flags.append(self.shards[s].write_batch(sub.stream, sub.lba, sub.fp))
         else:
             results: List[Optional[np.ndarray]] = [None] * self.num_shards
 
             def _run(s, sub):
-                results[s] = self.shards[s].write_batch(sub.stream, sub.lba, sub.fp)
+                with obs.span("shard.write_batch", shard=s, batch=batch, keys=len(sub)):
+                    results[s] = self.shards[s].write_batch(sub.stream, sub.lba, sub.fp)
 
             for s, sub in enumerate(parts):
                 if sub is not None and s not in self._failed:
                     self._submit_pinned(s, lambda s=s, sub=sub: _run(s, sub))
-            self._sync()
+            with obs.span("cluster.wait"):
+                self._sync()
             flags = [
                 results[s] if results[s] is not None else np.zeros(len(sub), dtype=bool)
                 for s, sub in enumerate(parts)
                 if sub is not None
             ]
-        if flags:
-            out[order] = np.concatenate(flags)
+        with obs.span("cluster.gather"):
+            if flags:
+                out[order] = np.concatenate(flags)
         return out
 
     @_locked
@@ -1899,6 +1919,7 @@ class ShardedCluster:
         cluster._executor = None  # executors are process-local, never restored
         cluster._workers_dirty = False
         cluster.min_parallel_batch = 2048
+        cluster.write_batches = 0
         # a snapshot taken mid-GC carries per-store deferred flags; shards
         # grown later must inherit the cluster-wide arming decision
         cluster._gc_deferred = any(e.store.deferred_reclaim for e in cluster.shards)

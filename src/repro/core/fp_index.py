@@ -43,7 +43,11 @@ consume so device probes overlap host work; tiny batches are answered by
 the host set, below the size where a vectorized launch wins
 (``small_batch``, set to 0 by tests that want the table path exercised
 unconditionally).  ``table_stats()`` counts the probed keys each path
-answered, so a run can show whether the device did the work.
+answered, so a run can show whether the device did the work, and the
+device launches by op with the keys they carried, the key slots they were
+padded to, the keys they placed and removed, and the keys probed while
+folding the journal.  Each probe, insert, remove and fold is one
+``dedup.fp_index.*`` profiler span (``repro.obs``).
 """
 
 from __future__ import annotations
@@ -52,9 +56,11 @@ from typing import Iterable
 
 import numpy as np
 
+from .. import obs
 from ..kernels.fp_index import (
     EMPTY32,
     OVERFLOW,
+    PLACED,
     PLACED_TOMB,
     TOMB32,
     WINDOW,
@@ -119,6 +125,12 @@ class FingerprintIndex(set):
         "small_batch",
         "_probed_device",
         "_probed_host",
+        "_launches_device",
+        "_launch_keys",
+        "_launch_key_slots",
+        "_inserted_device",
+        "_removed_device",
+        "_flush_probe_keys",
     )
 
     def __init__(
@@ -138,6 +150,16 @@ class FingerprintIndex(set):
         # small-batch set path or the numpy table)
         self._probed_device = 0
         self._probed_host = 0
+        # device launches by op, the real keys they carried and the key
+        # slots they were padded to (tiles x K), the keys insert launches
+        # placed and remove launches tombstoned, and the keys probed while
+        # folding the add_many journal
+        self._launches_device = {"probe": 0, "insert": 0, "remove": 0}
+        self._launch_keys = 0
+        self._launch_key_slots = 0
+        self._inserted_device = 0
+        self._removed_device = 0
+        self._flush_probe_keys = 0
         cap = 1
         while cap < capacity:
             cap <<= 1
@@ -163,6 +185,21 @@ class FingerprintIndex(set):
             self._dev_lo = jnp.asarray(tlo.reshape(shape))
             self._dev_hi = jnp.asarray(thi.reshape(shape))
         return self._dev_lo, self._dev_hi
+
+    def _launch(self, op: str, keys: np.ndarray) -> np.ndarray:
+        """One device launch of ``op`` over sentinel-free keys, counted;
+        returns the per-key int32 answer in batch order."""
+        from ..kernels.ops import fp_index_launch
+
+        lo, hi = _split(keys)
+        tlo, thi = self._dev_tables()
+        tables, answer, slots = fp_index_launch(op, lo, hi, tlo, thi, self._cap)
+        if tables is not None:
+            self._adopt_dev(*tables)
+        self._launches_device[op] += 1
+        self._launch_keys += keys.size
+        self._launch_key_slots += slots
+        return answer
 
     def _adopt_dev(self, tlo, thi) -> None:
         """Keep the in-place-updated buffers a launch returned; the host
@@ -249,16 +286,21 @@ class FingerprintIndex(set):
         return True
 
     def _flush(self) -> None:
-        """Fold pending mutations into the table.
+        """Fold pending mutations into the table (one ``fp_index.flush``
+        span, when there are any)."""
+        if not self._pending_adds and not self._pending_removes and not self._journal:
+            return
+        with obs.span("fp_index.flush",
+                      keys=len(self._pending_adds) + len(self._pending_removes) + self._journal_n):
+            self._fold()
 
-        Order matters: the scalar pending-add dict holds keys known absent
+    def _fold(self) -> None:
+        """Order matters: the scalar pending-add dict holds keys known absent
         from the table (direct insert), the ``add_many`` journal may hold
         anything (unique + probe-filter first), and removals fold last so a
         journaled key that was discarded after staging is inserted and then
         tombstoned — never left dangling in the table.
         """
-        if not self._pending_adds and not self._pending_removes and not self._journal:
-            return
         journal_keys = None
         if self._journal:
             journal_keys = (
@@ -282,6 +324,7 @@ class FingerprintIndex(set):
                 self._spill.update(k for k in journal_keys[special].tolist() if k in self)
                 journal_keys = journal_keys[~special]
             if journal_keys.size:
+                self._flush_probe_keys += journal_keys.size
                 known = self._table_probe(journal_keys)
                 fresh = journal_keys[~known]
                 if fresh.size:
@@ -308,121 +351,109 @@ class FingerprintIndex(set):
         window overflow spills to the host set."""
         if keys.size == 0:
             return
-        if self._use_pallas():
-            from ..kernels.ops import fp_index_insert
-
-            lo, hi = _split(keys)
-            tlo, thi = self._dev_tables()
-            tlo, thi, status = fp_index_insert(lo, hi, tlo, thi, self._cap)
-            self._adopt_dev(tlo, thi)
-            over = status == OVERFLOW
-            self._table_live += int(keys.size - over.sum())
-            self._tombstones -= int(np.count_nonzero(status == PLACED_TOMB))
-            if over.any():
-                self._spill.update(keys[over].tolist())
-            return
-        home = self._phys_homes(keys)
-        t64 = self._t64
-        tomb = np.uint64(TOMB_KEY)
-        for r in range(WINDOW):
-            if keys.size == 0:
+        with obs.span("fp_index.insert", keys=keys.size) as span:
+            if self._use_pallas():
+                status = self._launch("insert", keys)
+                over = status == OVERFLOW
+                placed = int(np.count_nonzero((status == PLACED) | (status == PLACED_TOMB)))
+                self._inserted_device += placed
+                span.set_metadata(placed=placed)
+                self._table_live += int(keys.size - over.sum())
+                self._tombstones -= int(np.count_nonzero(status == PLACED_TOMB))
+                if over.any():
+                    self._spill.update(keys[over].tolist())
                 return
-            slot = home + r
-            cur = t64[slot]
-            free = (cur == 0) | (cur == tomb)
-            cand = np.nonzero(free)[0]
-            if cand.size:
-                # one winner per distinct slot — writing candidates in
-                # *reversed* batch order makes the first-in-batch write
-                # land last and stick; losers (whose slot now holds the
-                # winner) probe the next offset, exactly as if the winner
-                # had been inserted before them
-                rev = cand[::-1]
-                t64[slot[rev]] = keys[rev]
-                won = t64[slot[cand]] == keys[cand]
-                win = cand[won]
-                self._tombstones -= int((cur[win] == tomb).sum())
-                self._table_live += win.size
-                if win.size == keys.size:
+            home = self._phys_homes(keys)
+            t64 = self._t64
+            tomb = np.uint64(TOMB_KEY)
+            for r in range(WINDOW):
+                if keys.size == 0:
                     return
-                keep = np.ones(keys.size, dtype=bool)
-                keep[win] = False
-                keys, home = keys[keep], home[keep]
-        if keys.size:
-            self._spill.update(keys.tolist())
+                slot = home + r
+                cur = t64[slot]
+                free = (cur == 0) | (cur == tomb)
+                cand = np.nonzero(free)[0]
+                if cand.size:
+                    # one winner per distinct slot — writing candidates in
+                    # *reversed* batch order makes the first-in-batch write
+                    # land last and stick; losers (whose slot now holds the
+                    # winner) probe the next offset, exactly as if the winner
+                    # had been inserted before them
+                    rev = cand[::-1]
+                    t64[slot[rev]] = keys[rev]
+                    won = t64[slot[cand]] == keys[cand]
+                    win = cand[won]
+                    self._tombstones -= int((cur[win] == tomb).sum())
+                    self._table_live += win.size
+                    if win.size == keys.size:
+                        return
+                    keep = np.ones(keys.size, dtype=bool)
+                    keep[win] = False
+                    keys, home = keys[keep], home[keep]
+            if keys.size:
+                self._spill.update(keys.tolist())
 
     def _table_remove(self, keys: np.ndarray) -> None:
         """Tombstone table slots for keys known resident in the table."""
         if keys.size == 0:
             return
-        if self._use_pallas():
-            from ..kernels.ops import fp_index_remove
-
-            lo, hi = _split(keys)
-            tlo, thi = self._dev_tables()
-            tlo, thi, removed = fp_index_remove(lo, hi, tlo, thi, self._cap)
-            self._adopt_dev(tlo, thi)
-            hits = int(np.count_nonzero(removed))
-            self._table_live -= hits
-            self._tombstones += hits
-            return
-        home = self._phys_homes(keys)
-        t64 = self._t64
-        for r in range(WINDOW):
-            if home.size == 0:
+        with obs.span("fp_index.remove", keys=keys.size):
+            if self._use_pallas():
+                hits = int(np.count_nonzero(self._launch("remove", keys)))
+                self._removed_device += hits
+                self._table_live -= hits
+                self._tombstones += hits
                 return
-            slot = home + r
-            match = t64[slot] == keys
-            if match.any():
-                t64[slot[match]] = np.uint64(TOMB_KEY)
-                self._table_live -= int(match.sum())
-                self._tombstones += int(match.sum())
-                keep = ~match
-                keys, home = keys[keep], home[keep]
-
-    def _table_probe_launch(self, keys: np.ndarray):
-        """Start an exact membership probe of sentinel-free keys against
-        table + spill; returns a zero-arg consumer producing the flags.
-
-        On the Pallas backend the kernel launch is dispatched immediately
-        and materialized only in the consumer, so the device probe overlaps
-        whatever host work runs in between (jax async dispatch).  The numpy
-        backend computes eagerly — there is nothing to overlap with.
-        """
-        if self._use_pallas():
-            from ..kernels.ops import fp_index_probe
-
-            lo, hi = _split(keys)
-            tlo, thi = self._dev_tables()
-
-            def consume(out=fp_index_probe(lo, hi, tlo, thi, self._cap)):
-                return self._spill_fixup(keys, out)
-
-            return consume
-        if self._table_live == 0:
-            found = np.zeros(keys.size, dtype=bool)
-        else:
             home = self._phys_homes(keys)
-            found = np.zeros(keys.size, dtype=bool)
-            idx = np.arange(keys.size)
-            rem = keys
             t64 = self._t64
             for r in range(WINDOW):
-                cur = t64[home + r]
-                match = cur == rem
+                if home.size == 0:
+                    return
+                slot = home + r
+                match = t64[slot] == keys
                 if match.any():
-                    found[idx[match]] = True
-                # EMPTY terminates a probe chain: inserts are first-fit, so a
-                # key never sits past a slot that was EMPTY when it arrived,
-                # and removals tombstone instead of emptying — the active set
-                # shrinks geometrically with the load factor, so most keys
-                # resolve within the first round or two
-                undecided = ~(match | (cur == 0))
-                if not undecided.any():
-                    break
-                idx, rem, home = idx[undecided], rem[undecided], home[undecided]
-        out = self._spill_fixup(keys, found)
-        return lambda: out
+                    t64[slot[match]] = np.uint64(TOMB_KEY)
+                    self._table_live -= int(match.sum())
+                    self._tombstones += int(match.sum())
+                    keep = ~match
+                    keys, home = keys[keep], home[keep]
+
+    def _table_probe_launch(self, keys: np.ndarray):
+        """Run an exact membership probe of sentinel-free keys against
+        table + spill; returns a zero-arg consumer producing the flags.
+
+        On the Pallas backend the launch reads its answer back before it
+        returns (``fp_index.fetch`` waits for the device), and the consumer
+        folds in the spill set.  The numpy backend computes eagerly.
+        """
+        with obs.span("fp_index.probe", keys=keys.size):
+            if self._use_pallas():
+                hit = self._launch("probe", keys) != 0
+                return lambda: self._spill_fixup(keys, hit)
+            if self._table_live == 0:
+                found = np.zeros(keys.size, dtype=bool)
+            else:
+                home = self._phys_homes(keys)
+                found = np.zeros(keys.size, dtype=bool)
+                idx = np.arange(keys.size)
+                rem = keys
+                t64 = self._t64
+                for r in range(WINDOW):
+                    cur = t64[home + r]
+                    match = cur == rem
+                    if match.any():
+                        found[idx[match]] = True
+                    # EMPTY terminates a probe chain: inserts are first-fit, so
+                    # a key never sits past a slot that was EMPTY when it
+                    # arrived, and removals tombstone instead of emptying — the
+                    # active set shrinks geometrically with the load factor, so
+                    # most keys resolve within the first round or two
+                    undecided = ~(match | (cur == 0))
+                    if not undecided.any():
+                        break
+                    idx, rem, home = idx[undecided], rem[undecided], home[undecided]
+            out = self._spill_fixup(keys, found)
+            return lambda: out
 
     def _spill_fixup(self, keys: np.ndarray, found: np.ndarray) -> np.ndarray:
         # consult the spill set unless it holds nothing but sentinel keys
@@ -453,7 +484,8 @@ class FingerprintIndex(set):
             return lambda: out
         if n <= self.small_batch:
             self._probed_host += n
-            out = np.fromiter(map(self.__contains__, keys.tolist()), dtype=bool, count=n)
+            with obs.span("fp_index.probe", keys=n):
+                out = np.fromiter(map(self.__contains__, keys.tolist()), dtype=bool, count=n)
             return lambda: out
         self._flush()
         consume = self._table_probe_launch(keys)
@@ -665,6 +697,12 @@ class FingerprintIndex(set):
             "device_bytes": 0 if self._dev_lo is None else self._dev_lo.nbytes + self._dev_hi.nbytes,
             "probed_device": self._probed_device,
             "probed_host": self._probed_host,
+            "launches_device": dict(self._launches_device),
+            "launch_keys": self._launch_keys,
+            "launch_key_slots": self._launch_key_slots,
+            "inserted_device": self._inserted_device,
+            "removed_device": self._removed_device,
+            "flush_probe_keys": self._flush_probe_keys,
         }
 
     def check_consistency(self) -> None:

@@ -251,6 +251,7 @@ def fp_probe_pallas(
             out_specs=key,
         ),
         interpret=interpret,
+        name="fp_probe",
     )(counts, keys_lo, keys_hi, slots, table_lo, table_hi)
 
 
@@ -326,7 +327,7 @@ def _insert_kernel(
     jax.lax.fori_loop(0, _tile_keys(cnt_ref), body, 0)
 
 
-def _mutate_call(kernel, counts, keys_lo, keys_hi, table_lo, table_hi, cap, interpret):
+def _mutate_call(kernel, name, counts, keys_lo, keys_hi, table_lo, table_hi, cap, interpret):
     t, k, rows = _check_tiled(counts, keys_lo, table_lo, cap)
     slots = _in_tile_slots(keys_lo, keys_hi, tile_shape(cap)[1])
     key = _key_spec(k)
@@ -346,6 +347,7 @@ def _mutate_call(kernel, counts, keys_lo, keys_hi, table_lo, table_hi, cap, inte
         # operand indices count the scalar-prefetch counts (operand 0)
         input_output_aliases={4: 0, 5: 1},
         interpret=interpret,
+        name=name,
     )(counts, keys_lo, keys_hi, slots, table_lo, table_hi)
 
 
@@ -357,8 +359,8 @@ def fp_insert_pallas(counts, keys_lo, keys_hi, table_lo, table_hi, *, cap: int,
     updated in place on device (input/output aliasing) — steady-state
     launches transfer keys only.
     """
-    return _mutate_call(_insert_kernel, counts, keys_lo, keys_hi, table_lo, table_hi, cap,
-                        interpret)
+    return _mutate_call(_insert_kernel, "fp_insert", counts, keys_lo, keys_hi, table_lo, table_hi,
+                        cap, interpret)
 
 
 def _remove_kernel(
@@ -395,5 +397,5 @@ def fp_remove_pallas(counts, keys_lo, keys_hi, table_lo, table_hi, *, cap: int,
     ``status`` is 1 where a slot was tombstoned, 0 on a miss.  In-place on
     device, keys-only transfer, like insert.
     """
-    return _mutate_call(_remove_kernel, counts, keys_lo, keys_hi, table_lo, table_hi, cap,
-                        interpret)
+    return _mutate_call(_remove_kernel, "fp_remove", counts, keys_lo, keys_hi, table_lo, table_hi,
+                        cap, interpret)

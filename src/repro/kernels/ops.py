@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from .cdc import HALO_WORDS, cdc_candidates_pallas
 from .fingerprint import LANES, NUM_HASHES, TILE_B, fingerprint_pallas
 from .fp_index import (
@@ -275,12 +276,31 @@ def _table_pair(table_lo, table_hi, cap: int):
     return tlo.reshape(shape), thi.reshape(shape)
 
 
-def _launch(jitted, keys_lo, keys_hi, table_lo, table_hi, cap, interpret):
-    counts, klo, khi, flat_pos = _route_keys(keys_lo, keys_hi, cap)
-    tlo, thi = _table_pair(table_lo, table_hi, cap)
-    out = jitted(jnp.asarray(counts), jnp.asarray(klo), jnp.asarray(khi), tlo, thi,
-                 cap=cap, interpret=_interpret(interpret))
-    return out, flat_pos
+_FP_INDEX_JITS = {"probe": _fp_probe_jit, "insert": _fp_insert_jit, "remove": _fp_remove_jit}
+
+
+def fp_index_launch(op: str, keys_lo, keys_hi, table_lo, table_hi, cap: int,
+                    interpret: bool | None = None):
+    """One fp-index kernel launch: route the keys to their home tiles, ship
+    them, run ``op`` (``probe``/``insert``/``remove``) and read its per-key
+    answer back.
+
+    Returns ``(tables, answer, slots)``: the updated ``(table_lo,
+    table_hi)`` device buffers (None for a probe), the (N,) int32 per-key
+    answer in batch order (probe hit, insert status, remove hit) and the key
+    slots the launch was padded to (tiles x ``K``).
+    """
+    n = len(keys_lo)
+    with obs.span("fp_index.route_keys", keys=n):
+        counts, klo, khi, flat_pos = _route_keys(keys_lo, keys_hi, cap)
+    with obs.span("fp_index.put", keys=n, slots=klo.size):
+        tlo, thi = _table_pair(table_lo, table_hi, cap)
+        out = _FP_INDEX_JITS[op](jnp.asarray(counts), jnp.asarray(klo), jnp.asarray(khi), tlo,
+                                 thi, cap=cap, interpret=_interpret(interpret))
+    tables, answer = (None, out) if op == "probe" else (out[:2], out[2])
+    with obs.span("fp_index.fetch", keys=n):
+        answer = np.asarray(answer)[flat_pos]
+    return tables, answer, klo.size
 
 
 def fp_index_probe(keys_lo, keys_hi, table_lo, table_hi, cap: int,
@@ -293,8 +313,7 @@ def fp_index_probe(keys_lo, keys_hi, table_lo, table_hi, cap: int,
     tiles host-side and padded per tile (pad flags are dropped in the
     scatter-back).
     """
-    out, flat_pos = _launch(_fp_probe_jit, keys_lo, keys_hi, table_lo, table_hi, cap, interpret)
-    return np.asarray(out)[flat_pos] != 0
+    return fp_index_launch("probe", keys_lo, keys_hi, table_lo, table_hi, cap, interpret)[1] != 0
 
 
 def fp_index_insert(keys_lo, keys_hi, table_lo, table_hi, cap: int,
@@ -306,10 +325,9 @@ def fp_index_insert(keys_lo, keys_hi, table_lo, table_hi, cap: int,
     keep them resident for the next launch and only materialize a host
     mirror on demand.  ``status`` is a (N,) numpy array in batch order
     (PLACED / PRESENT / OVERFLOW / PLACED_TOMB per ``kernels.fp_index``)."""
-    (tlo, thi, status), flat_pos = _launch(
-        _fp_insert_jit, keys_lo, keys_hi, table_lo, table_hi, cap, interpret
-    )
-    return tlo, thi, np.asarray(status)[flat_pos]
+    (tlo, thi), status, _ = fp_index_launch("insert", keys_lo, keys_hi, table_lo, table_hi, cap,
+                                            interpret)
+    return tlo, thi, status
 
 
 def fp_index_remove(keys_lo, keys_hi, table_lo, table_hi, cap: int,
@@ -318,10 +336,9 @@ def fp_index_remove(keys_lo, keys_hi, table_lo, table_hi, cap: int,
 
     Like ``fp_index_insert``: device-resident in-place update, keys-only
     transfer.  ``removed`` is a (N,) bool numpy array in batch order."""
-    (tlo, thi, status), flat_pos = _launch(
-        _fp_remove_jit, keys_lo, keys_hi, table_lo, table_hi, cap, interpret
-    )
-    return tlo, thi, np.asarray(status)[flat_pos] != 0
+    (tlo, thi), status, _ = fp_index_launch("remove", keys_lo, keys_hi, table_lo, table_hi, cap,
+                                            interpret)
+    return tlo, thi, status != 0
 
 
 @functools.partial(jax.jit, static_argnames=("nbins", "interpret"))

@@ -44,6 +44,8 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from .. import obs
+
 
 @dataclasses.dataclass
 class TenantQoS:
@@ -116,6 +118,15 @@ class AsyncDedupFrontend:
         self.tenants: Dict[int, TenantQoS] = {}
         self.batches_executed = 0
         self.records_executed = 0
+        # seconds, summed over batches: first buffered write to close (fill),
+        # close to the engine thread's start (queue wait), and the
+        # acknowledgement loop (ack)
+        self.fill_s = 0.0
+        self.queue_wait_s = 0.0
+        self.ack_s = 0.0
+        self._batches_closed = 0  # the ``batch`` stat of the front end's spans
+        self._fill = None  # the open batch's frontend.fill span
+        self._fill_t0 = 0.0
         self._executed: List[Tuple[np.ndarray, np.ndarray, np.ndarray]] = []
         self._cap_memo: Optional[Tuple[Dict[int, int], int]] = None
         self._closed = False
@@ -204,28 +215,39 @@ class AsyncDedupFrontend:
         """Close the open batch and hand it to the engine thread."""
         if not self._buf_futs:
             return
-        tenants = np.asarray(self._buf_tenants, dtype=np.int64)
-        lbas = np.asarray(self._buf_lbas, dtype=np.int64)
-        fps = np.asarray(self._buf_fps, dtype=np.uint64)
-        futs = self._buf_futs
-        t0s = self._buf_t0
-        self._buf_tenants, self._buf_lbas, self._buf_fps = [], [], []
-        self._buf_futs, self._buf_t0 = [], []
-        loop = asyncio.get_running_loop()
-        self._inflight_batches += 1
-        job = loop.run_in_executor(self._engine_pool, self._execute_batch, tenants, lbas, fps)
-        job.add_done_callback(lambda f, futs=futs, t0s=t0s, tenants=tenants: (
-            self._on_batch_done(f, futs, t0s, tenants)
-        ))
+        self._fill.__exit__(None, None, None)
+        t_close = time.perf_counter()
+        self.fill_s += t_close - self._fill_t0
+        batch = self._batches_closed
+        self._batches_closed = batch + 1
+        with obs.span("frontend.close", batch=batch, keys=len(self._buf_futs)):
+            tenants = np.asarray(self._buf_tenants, dtype=np.int64)
+            lbas = np.asarray(self._buf_lbas, dtype=np.int64)
+            fps = np.asarray(self._buf_fps, dtype=np.uint64)
+            futs = self._buf_futs
+            t0s = self._buf_t0
+            self._buf_tenants, self._buf_lbas, self._buf_fps = [], [], []
+            self._buf_futs, self._buf_t0 = [], []
+            loop = asyncio.get_running_loop()
+            self._inflight_batches += 1
+            job = loop.run_in_executor(self._engine_pool, self._execute_batch, tenants, lbas, fps,
+                                       batch, t_close)
+            job.add_done_callback(lambda f, futs=futs, t0s=t0s, tenants=tenants: (
+                self._on_batch_done(f, futs, t0s, tenants, batch)
+            ))
 
-    def _execute_batch(self, tenants: np.ndarray, lbas: np.ndarray, fps: np.ndarray):
+    def _execute_batch(self, tenants: np.ndarray, lbas: np.ndarray, fps: np.ndarray, batch: int,
+                       t_close: float):
         """Engine-thread body: one columnar write_batch (shards may fan out
         onto the cluster's own worker threads underneath)."""
+        wait = time.perf_counter() - t_close
+        self.queue_wait_s += wait
         if self.record_trace:
             self._executed.append((tenants, lbas, fps))
-        return self.engine.write_batch(tenants, lbas, fps)
+        with obs.span("frontend.execute", batch=batch, queue_us=int(wait * 1e6)):
+            return self.engine.write_batch(tenants, lbas, fps)
 
-    def _on_batch_done(self, job, futs, t0s, tenants) -> None:
+    def _on_batch_done(self, job, futs, t0s, tenants, batch: int) -> None:
         now = time.perf_counter()
         self.batches_executed += 1
         self.records_executed += len(futs)
@@ -233,24 +255,26 @@ class AsyncDedupFrontend:
         self._cap_memo = None  # estimator/cache state moved: recompute caps
         err = job.exception()
         flags = None if err is not None else job.result()
-        for i, fut in enumerate(futs):
-            tenant = int(tenants[i])
-            self._inflight[tenant] -= 1
-            self._sem.release()
-            q = self._qos(tenant)
-            if err is not None:
+        with obs.span("frontend.ack", batch=batch, keys=len(futs)):
+            for i, fut in enumerate(futs):
+                tenant = int(tenants[i])
+                self._inflight[tenant] -= 1
+                self._sem.release()
+                q = self._qos(tenant)
+                if err is not None:
+                    if not fut.done():
+                        fut.set_exception(err)
+                    continue
+                q.completed += 1
+                deduped = bool(flags[i])
+                q.deduped += int(deduped)
+                q.latencies.append(now - t0s[i])
                 if not fut.done():
-                    fut.set_exception(err)
-                continue
-            q.completed += 1
-            deduped = bool(flags[i])
-            q.deduped += int(deduped)
-            q.latencies.append(now - t0s[i])
-            if not fut.done():
-                fut.set_result(deduped)
+                    fut.set_result(deduped)
         # wake admission-cap waiters so they re-check their budget
         self._drained.set()
         self._drained.clear()
+        self.ack_s += time.perf_counter() - now
 
     # -- client surface --------------------------------------------------------
     async def write(self, tenant: int, fp: int, lba: Optional[int] = None) -> bool:
@@ -274,6 +298,10 @@ class AsyncDedupFrontend:
             lba = self._next_lba.get(tenant, 0)
             self._next_lba[tenant] = lba + 1
         fut = asyncio.get_running_loop().create_future()
+        if not self._buf_futs:  # a batch opens: its fill span runs until _flush
+            self._fill = obs.span("frontend.fill", batch=self._batches_closed)
+            self._fill.__enter__()
+            self._fill_t0 = time.perf_counter()
         self._buf_tenants.append(int(tenant))
         self._buf_lbas.append(int(lba))
         self._buf_fps.append(int(fp))
@@ -379,6 +407,9 @@ class AsyncDedupFrontend:
             "mean_batch": round(self.records_executed / self.batches_executed, 1)
             if self.batches_executed
             else 0.0,
+            "fill_s": self.fill_s,
+            "queue_wait_s": self.queue_wait_s,
+            "ack_s": self.ack_s,
             "p50_ms": round(float(np.percentile(arr, 50)) * 1e3, 3) if all_lat else 0.0,
             "p99_ms": round(float(np.percentile(arr, 99)) * 1e3, 3) if all_lat else 0.0,
         }
