@@ -313,11 +313,11 @@ def _certify_staged(store, w_streams: np.ndarray, w_lbas: np.ndarray, pending_ke
     mins = sl[starts].tolist()
     maxs = sl[np.concatenate((cuts, [nw])) - 1].tolist()
 
-    lm = store.lba_map
+    lm = store._lba_pba  # keyed by store.lba_key: (stream << 64) + lba
     wm = store._lba_watermark
     if pending_keys:
-        for key in pending_keys:
-            if key in lm:
+        for s, lba in pending_keys:
+            if (s << 64) + lba in lm:
                 return False
     fast = all(mn >= wm.get(s, 0) for s, mn in zip(su, mins))
     if fast and pending_keys:
@@ -325,11 +325,12 @@ def _certify_staged(store, w_streams: np.ndarray, w_lbas: np.ndarray, pending_ke
         # key; below it, batch keys (all >= watermark) can never touch it
         fast = all(lba < wm.get(s, 0) for s, lba in pending_keys)
     if not fast:
+        keys = zip(w_streams.tolist(), w_lbas.tolist())
         if pending_keys:
-            for key in zip(w_streams.tolist(), w_lbas.tolist()):
-                if key in lm or key in pending_keys:
+            for key in keys:
+                if (key[0] << 64) + key[1] in lm or key in pending_keys:
                     return False
-        elif any(map(lm.__contains__, zip(w_streams.tolist(), w_lbas.tolist()))):
+        elif any((s << 64) + lba in lm for s, lba in keys):
             return False
     for s, mx in zip(su, maxs):
         if mx >= wm.get(s, 0):
@@ -486,7 +487,7 @@ def _hpdedup_decide(hp, rb: ReplayBatch, out: Optional[np.ndarray], base: int, i
         # fully inlined staged loop: store mutations are local list appends /
         # dict sets; run decisions mirror InlineDedupEngine._decide_run with
         # staged writes (TOCTOU guard included)
-        lm = store.lba_map
+        lm = store._lba_pba  # keyed by store.lba_key: (stream << 64) + lba
         fp_of = store.fp_of_pba
         sw_append = store._staged_writes.append
         sd_append = store._staged_dups.append
@@ -508,12 +509,13 @@ def _hpdedup_decide(hp, rb: ReplayBatch, out: Optional[np.ndarray], base: int, i
         def decide(s, run):
             nonlocal pba_next, inline_dups_c, broken_c
             items = run.items
+            sk = s << 64
             record_dup_run(s, len(items))
             if len(items) >= threshold_of(s):
                 if not check_stale:
                     # no PBA has ever been freed: every item is a valid dup,
                     # so the whole run applies through C-driven bulk updates
-                    lm.update(((s, it[0]), it[2]) for it in items)
+                    lm.update((sk + it[0], it[2]) for it in items)
                     sd_extend([it[2] for it in items])
                     run_dups = len(items)
                 else:
@@ -524,11 +526,11 @@ def _hpdedup_decide(hp, rb: ReplayBatch, out: Optional[np.ndarray], base: int, i
                             p_new = pba_next
                             pba_next = p_new + 1
                             fp_of[p_new] = f2
-                            lm[(s, lba2)] = p_new
+                            lm[sk + lba2] = p_new
                             sw_append((f2, p_new))
                             admit(s, f2, p_new)
                             continue
-                        lm[(s, lba2)] = p2
+                        lm[sk + lba2] = p2
                         sd_append(p2)
                         run_dups += 1
                 if run_dups:
@@ -540,7 +542,7 @@ def _hpdedup_decide(hp, rb: ReplayBatch, out: Optional[np.ndarray], base: int, i
                     p_new = pba_next
                     pba_next = p_new + 1
                     fp_of[p_new] = f2
-                    lm[(s, lba2)] = p_new
+                    lm[sk + lba2] = p_new
                     sw_append((f2, p_new))
                     admit(s, f2, p_new)
 
@@ -581,7 +583,7 @@ def _hpdedup_decide(hp, rb: ReplayBatch, out: Optional[np.ndarray], base: int, i
                     p_new = pba_next
                     pba_next = p_new + 1
                     fp_of[p_new] = f
-                    lm[(s, lba)] = p_new
+                    lm[(s << 64) + lba] = p_new
                     sw_append((f, p_new))
                     admit(s, f, p_new)
             else:
@@ -906,7 +908,7 @@ def _postproc_bulk(pp, rb: ReplayBatch) -> None:
         pba0 = store._next_pba
         pbas = range(pba0, pba0 + nw)
         store._next_pba = pba0 + nw
-        store.lba_map.update(zip(zip(ws_l, wl_l), pbas))
+        store._lba_pba.update(zip([(s << 64) + lba for s, lba in zip(ws_l, wl_l)], pbas))
         store.fp_of_pba.update(zip(pbas, wf_l))
         store._staged_writes.extend(zip(wf_l, pbas))
     else:

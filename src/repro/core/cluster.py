@@ -84,10 +84,12 @@ from .batch_replay import (
     engine_run_batch,
 )
 from .fingerprint import OP_WRITE, TRACE_DTYPE
+from .fp_index import FingerprintIndex
 from .hybrid import HPDedup, HybridReport
 from .inline_engine import InlineMetrics
 from .postprocess import PostProcessMetrics
 from .statetree import from_pairs, pairs
+from .store import lba_of_key
 
 # Packed (stream, lba) routing-directory keys: stream << LBA_BITS | lba.
 # 2^40 block addresses per stream (4 PiB volumes at 4 KB blocks) covers every
@@ -501,6 +503,8 @@ class ShardedCluster:
         self.min_parallel_batch = 2048
         # write_batch calls so far: the ``batch`` stat of its profiler spans
         self.write_batches = 0
+        # garbage collections as profiler spans too (one hook per process)
+        obs.trace_gc()
         # coordinator mutual exclusion (see _locked) + executor fault state:
         # shards whose worker raised are poisoned until fail/recover or a
         # snapshot reload re-establishes their state
@@ -1920,6 +1924,7 @@ class ShardedCluster:
         cluster._workers_dirty = False
         cluster.min_parallel_batch = 2048
         cluster.write_batches = 0
+        obs.trace_gc()
         # a snapshot taken mid-GC carries per-store deferred flags; shards
         # grown later must inherit the cluster-wide arming decision
         cluster._gc_deferred = any(e.store.deferred_reclaim for e in cluster.shards)
@@ -1929,15 +1934,16 @@ class ShardedCluster:
         return cluster
 
 
-def _seen_set_of(engine) -> Optional[set]:
+def _seen_set_of(engine):
     """The engine's ground-truth seen-fingerprint set (None if unknown).
 
-    For the built-in engines this is a ``FingerprintIndex`` (a ``set``
-    subclass), so membership transplants during resharding keep its
-    device-layout table coherent through the overridden mutators."""
+    For the built-in engines this is a ``FingerprintIndex`` (a
+    ``MutableSet``), so membership transplants during resharding keep its
+    device-layout table coherent through its own mutators; a custom engine
+    may hold a plain ``set``."""
     for attr in ("_seen_fps", "_seen"):
         seen = getattr(engine, attr, None)
-        if isinstance(seen, set):
+        if isinstance(seen, (set, FingerprintIndex)):
             return seen
     return None
 
@@ -2002,7 +2008,7 @@ def _migrate_fp(src, dst, fp: int, directory: Dict[int, int], t: int):
     if not pbas:
         return 0, moved_cache
     for pba in pbas:
-        keys = src_store.lbas_of_pba.pop(pba, set())
+        keys = src_store.pop_lbas(pba)
         dst_store.fp_of_pba[pba] = fp
         dst_store.refcount[pba] = src_store.refcount.pop(pba)
         del src_store.fp_of_pba[pba]
@@ -2010,13 +2016,14 @@ def _migrate_fp(src, dst, fp: int, directory: Dict[int, int], t: int):
         dst_store.live_blocks += 1
         src_store.buffer.invalidate(pba)
         for key in keys:
-            del src_store.lba_map[key]
-            dst_store.lba_map[key] = pba
-            directory[(key[0] << _LBA_BITS) + key[1]] = t
-            if key[1] >= dst_store._lba_watermark.get(key[0], 0):
-                dst_store._lba_watermark[key[0]] = key[1] + 1
+            del src_store._lba_pba[key]
+            dst_store._lba_pba[key] = pba
+            stream, lba = lba_of_key(key)
+            directory[(stream << _LBA_BITS) + lba] = t
+            if lba >= dst_store._lba_watermark.get(stream, 0):
+                dst_store._lba_watermark[stream] = lba + 1
         if not dst_store._reverse_dirty:
-            dst_store.lbas_of_pba[pba] = set(keys)
+            dst_store.put_lbas(pba, keys)
     # absorb keeps the destination's fingerprint index and duplicate-
     # candidate set coherent (a migrated fp landing on a shard that already
     # holds it is exactly the cross-shard duplicate reconcile later merges)

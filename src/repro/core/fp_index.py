@@ -10,8 +10,12 @@ cluster's multi-shard scatter probe all hold one of these.  It pairs
   either by the Pallas kernel set (the TPU default; interpret mode when
   forced on the CPU) or by a bit-identical vectorized numpy implementation
   (the CPU default) — with
-* the **authoritative host state** — the index *is a* ``set`` of Python
-  int fingerprints; the set is the ground truth the table accelerates.
+* the **authoritative host state** — a plain ``dict`` of Python int
+  fingerprints (each mapped to ``None``), the ground truth the table
+  accelerates.  A dict that holds only ints is one the cyclic garbage
+  collector does not track, so an aged index of millions of keys costs a
+  full collection nothing; a ``set`` (or a ``set`` subclass) is always
+  tracked, and every full pass would walk each of its entries.
 
 On the Pallas backend the lane arrays are **persistent device buffers**:
 insert/remove launches alias them in place and ship keys only, and the
@@ -52,6 +56,7 @@ folding the journal.  Each probe, insert, remove and fold is one
 
 from __future__ import annotations
 
+from collections.abc import MutableSet
 from typing import Iterable
 
 import numpy as np
@@ -95,18 +100,22 @@ def _split(keys: np.ndarray):
     return (keys & _U32).astype(np.uint32), (keys >> np.uint64(32)).astype(np.uint32)
 
 
-class FingerprintIndex(set):
+class FingerprintIndex(MutableSet):
     """Exact membership index over 64-bit fingerprints.
 
-    Subclasses ``set`` so every host-side consumer of the engines' seen
-    sets (snapshots, resharding migration, harness population scans) keeps
-    working unchanged — the set *is* the authoritative state; the table,
-    spill and pending buffers are the device-resident acceleration layered
-    on top.  All mutations must go through the overridden mutators (they
-    keep the table coherent); the read-only ``set`` API is inherited as is.
+    A ``collections.abc.MutableSet``: every host-side consumer of the
+    engines' seen sets (snapshots sort it, resharding migration unions and
+    discards it, harness population scans iterate it) gets the set API —
+    ``in``, ``len``, iteration, comparisons and ``|``/``&``/``-``/``^``
+    with sets, which return plain ``set`` objects.  The authoritative
+    membership is ``_keys``, an untracked ``dict`` of int -> None; the
+    table, spill and pending buffers are the device-resident acceleration
+    layered on top.  All mutations go through the mutators below (they keep
+    the table coherent).
     """
 
     __slots__ = (
+        "_keys",
         "_cap",
         "_tile_shift",
         "_tile_pad",
@@ -141,7 +150,7 @@ class FingerprintIndex(set):
         backend: str = "auto",
         small_batch: int = SMALL_BATCH,
     ):
-        super().__init__(keys)
+        self._keys = dict.fromkeys(keys)
         if backend not in ("auto", "numpy", "pallas"):
             raise ValueError(f"backend must be auto|numpy|pallas, got {backend!r}")
         self._backend = backend
@@ -242,7 +251,7 @@ class FingerprintIndex(set):
         mutations (the set already reflects them), clears spill back to what
         genuinely cannot live in the table, and invalidates the device
         buffers — the next launch re-uploads the fresh table."""
-        n_set = len(self)
+        n_set = len(self._keys)
         while n_set > GROW_LOAD * cap:
             cap <<= 1
         self._cap = cap
@@ -256,7 +265,7 @@ class FingerprintIndex(set):
         self._t64 = np.zeros(table_phys_len(cap), dtype=np.uint64)
         self._dev_lo = self._dev_hi = None
         self._host_dirty = False
-        self._spill = {k for k in (EMPTY_KEY, TOMB_KEY) if k in self}
+        self._spill = {k for k in (EMPTY_KEY, TOMB_KEY) if k in self._keys}
         self._pending_adds = {}
         self._pending_removes = {}
         self._journal = []
@@ -264,7 +273,7 @@ class FingerprintIndex(set):
         self._table_live = 0
         self._tombstones = 0
         if n_set > len(self._spill):
-            keys = np.fromiter(self, dtype=np.uint64, count=n_set)
+            keys = np.fromiter(self._keys, dtype=np.uint64, count=n_set)
             if self._spill:
                 keys = keys[(keys != np.uint64(EMPTY_KEY)) & (keys != np.uint64(TOMB_KEY))]
             for a in range(0, keys.size, 1 << 16):
@@ -321,7 +330,7 @@ class FingerprintIndex(set):
                 journal_keys == np.uint64(TOMB_KEY)
             )
             if special.any():
-                self._spill.update(k for k in journal_keys[special].tolist() if k in self)
+                self._spill.update(k for k in journal_keys[special].tolist() if k in self._keys)
                 journal_keys = journal_keys[~special]
             if journal_keys.size:
                 self._flush_probe_keys += journal_keys.size
@@ -485,7 +494,8 @@ class FingerprintIndex(set):
         if n <= self.small_batch:
             self._probed_host += n
             with obs.span("fp_index.probe", keys=n):
-                out = np.fromiter(map(self.__contains__, keys.tolist()), dtype=bool, count=n)
+                out = np.fromiter(map(self._keys.__contains__, keys.tolist()), dtype=bool,
+                                  count=n)
             return lambda: out
         self._flush()
         consume = self._table_probe_launch(keys)
@@ -522,7 +532,7 @@ class FingerprintIndex(set):
             fresh = uniq[~known]
             if fresh.size == 0:
                 return known
-            super(FingerprintIndex, self).update(fresh.tolist())
+            self._keys.update(dict.fromkeys(fresh.tolist()))
             if fresh.size <= self.small_batch:
                 # stage through the pending buffer like scalar adds (the keys
                 # are not in the set yet per `known`, so the invariant holds)
@@ -563,7 +573,7 @@ class FingerprintIndex(set):
         keys = np.ascontiguousarray(fps, dtype=np.uint64)
         if keys.size == 0:
             return
-        super().update(keys.tolist())
+        self._keys.update(dict.fromkeys(keys.tolist()))
         if self._pending_removes:
             # a re-added key whose tombstone is still pending sits in the
             # table: the fold would find it there, then tombstone it
@@ -578,11 +588,14 @@ class FingerprintIndex(set):
         if keys.size == 0:
             return
         self._flush()
-        present = np.fromiter(map(self.__contains__, keys.tolist()), dtype=bool, count=keys.size)
+        members = self._keys
+        present = np.fromiter(map(members.__contains__, keys.tolist()), dtype=bool,
+                              count=keys.size)
         keys = keys[present]
         if keys.size == 0:
             return
-        super().difference_update(keys.tolist())
+        for k in keys.tolist():
+            del members[k]
         in_spill = np.fromiter(
             map(self._spill.__contains__, keys.tolist()), dtype=bool, count=keys.size
         )
@@ -591,11 +604,26 @@ class FingerprintIndex(set):
             keys = keys[~in_spill]
         self._table_remove(keys)
 
+    # -- set API ---------------------------------------------------------------
+    def __contains__(self, fp) -> bool:
+        return fp in self._keys
+
+    def __iter__(self):
+        return iter(self._keys)
+
+    def __len__(self) -> int:
+        return len(self._keys)
+
+    @classmethod
+    def _from_iterable(cls, it) -> set:
+        # the binary operators build their result through this: a plain set
+        return set(it)
+
     # -- scalar mutators (pending-buffer staged) -------------------------------
     def add(self, fp: int) -> None:
-        if fp in self:
+        if fp in self._keys:
             return
-        super().add(fp)
+        self._keys[fp] = None
         if fp == EMPTY_KEY or fp == TOMB_KEY:
             self._spill.add(fp)
         elif fp in self._pending_removes:
@@ -604,9 +632,9 @@ class FingerprintIndex(set):
             self._pending_adds[fp] = None
 
     def discard(self, fp: int) -> None:
-        if fp not in self:
+        if fp not in self._keys:
             return
-        super().discard(fp)
+        del self._keys[fp]
         if fp == EMPTY_KEY or fp == TOMB_KEY:
             # sentinels only ever live in spill (or an unfolded journal —
             # the fold re-checks set membership, so dropping it here is
@@ -623,12 +651,12 @@ class FingerprintIndex(set):
             self._pending_removes[fp] = None
 
     def remove(self, fp: int) -> None:
-        if fp not in self:
+        if fp not in self._keys:
             raise KeyError(fp)
         self.discard(fp)
 
     def pop(self) -> int:
-        for fp in self:
+        for fp in self._keys:
             self.discard(fp)
             return fp
         raise KeyError("pop from an empty FingerprintIndex")
@@ -647,15 +675,15 @@ class FingerprintIndex(set):
                 self.discard(fp)
 
     def intersection_update(self, *others) -> None:
-        keep = set(self)
+        keep = set(self._keys)
         for other in others:
             keep &= set(other)
-        for fp in [k for k in self if k not in keep]:
+        for fp in [k for k in self._keys if k not in keep]:
             self.discard(fp)
 
     def symmetric_difference_update(self, other) -> None:
         for fp in set(other):
-            if fp in self:
+            if fp in self._keys:
                 self.discard(fp)
             else:
                 self.add(fp)
@@ -677,7 +705,7 @@ class FingerprintIndex(set):
         return self
 
     def clear(self) -> None:
-        super().clear()
+        self._keys.clear()
         self._rebuild(self._cap)
 
     # -- diagnostics / tests ---------------------------------------------------
@@ -715,4 +743,4 @@ class FingerprintIndex(set):
         assert len(occupied) == len(table_keys), "duplicate table entries"
         assert len(occupied) == self._table_live, (len(occupied), self._table_live)
         assert table_keys.isdisjoint(self._spill)
-        assert table_keys | self._spill == set(self), "table+spill != authoritative set"
+        assert table_keys | self._spill == set(self._keys), "table+spill != authoritative set"

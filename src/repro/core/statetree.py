@@ -19,7 +19,7 @@ without cycles.
 
 from __future__ import annotations
 
-from typing import Callable, Dict, Iterable, List, Tuple
+from typing import Callable, Dict, Iterable, List
 
 
 def pairs(d: Dict) -> List[list]:
@@ -32,12 +32,3 @@ def from_pairs(items: Iterable, key: Callable = int, value: Callable = None) -> 
     if value is None:
         return {key(k): v for k, v in items}
     return {key(k): value(v) for k, v in items}
-
-
-def kv3(d: Dict[Tuple[int, int], int]) -> List[list]:
-    """(a, b) -> v dict (e.g. the LBA map) as ``[[a, b, v], ...]`` triples."""
-    return [[a, b, v] for (a, b), v in d.items()]
-
-
-def from_kv3(items: Iterable) -> Dict[Tuple[int, int], int]:
-    return {(int(a), int(b)): int(v) for a, b, v in items}

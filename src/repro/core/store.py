@@ -2,10 +2,14 @@
 
 Models the primary storage stack HPDedup manages:
 
-* **LBA mapping table** — (stream, LBA) -> PBA (NVRAM in the paper).
+* **LBA mapping table** — (stream, LBA) -> PBA (NVRAM in the paper), keyed
+  by one packed int per (stream, LBA) (``lba_key``); ``lba_map`` is its
+  read-only view with tuple keys.
 * **On-disk fingerprint table** — fingerprint -> list of PBAs holding that
   content (the post-processing phase scans it; >1 PBA per fingerprint means
-  inline missed a duplicate).
+  inline missed a duplicate).  Held as fingerprint -> canonical PBA, with
+  the whole row only for the fingerprints stored at more than one PBA
+  (``fp_table`` is the read-only view of both).
 * **Reference counts** — per-PBA; the garbage collector frees PBAs at 0.
 * **D-LRU data buffer** — SSD staging buffer for recently accessed blocks.
 
@@ -17,10 +21,11 @@ from __future__ import annotations
 
 import threading
 from collections import OrderedDict
+from collections.abc import Mapping
 from typing import Callable, Dict, List, Optional, Tuple
 
 from .fp_index import FingerprintIndex
-from .statetree import from_kv3, from_pairs, kv3, pairs
+from .statetree import from_pairs, pairs
 
 
 class DLRUBuffer:
@@ -61,22 +66,95 @@ class DLRUBuffer:
         self.misses = int(tree["misses"])
 
 
+def lba_key(stream: int, lba: int) -> int:
+    """A (stream, lba) key as one int, ``stream * 2**64 + lba``: exact for
+    every int64 LBA.  A dict keyed by these (and holding ints) is one the
+    garbage collector never tracks, where a fresh tuple key re-tracks it.
+    Hot loops inline the same ``(stream << 64) + lba``."""
+    return (stream << 64) + lba
+
+
+def lba_of_key(key: int) -> Tuple[int, int]:
+    """``lba_key``'s inverse."""
+    stream = (key + (1 << 63)) >> 64
+    return stream, key - (stream << 64)
+
+
+class LbaMap(Mapping):
+    """Read-only ``(stream, lba) -> PBA`` view of a store's LBA mapping
+    table, which is keyed by ``lba_key`` ints."""
+
+    __slots__ = ("_store",)
+
+    def __init__(self, store: "BlockStore"):
+        self._store = store
+
+    def __getitem__(self, key: Tuple[int, int]) -> int:
+        return self._store._lba_pba[lba_key(*key)]
+
+    def __iter__(self):
+        return map(lba_of_key, self._store._lba_pba)
+
+    def __len__(self) -> int:
+        return len(self._store._lba_pba)
+
+
+class FpTable(Mapping):
+    """Read-only ``fingerprint -> [PBA, ...]`` view of a store's fingerprint
+    table, canonical PBA first: each lookup builds the list afresh from the
+    store's two maps, so a reader cannot mutate the table through it."""
+
+    __slots__ = ("_store",)
+
+    def __init__(self, store: "BlockStore"):
+        self._store = store
+
+    def __getitem__(self, fp: int) -> List[int]:
+        st = self._store
+        pbas = st._dup_fps.get(fp)
+        return list(pbas) if pbas is not None else [st._fp_pba[fp]]
+
+    def __iter__(self):
+        return iter(self._store._fp_pba)
+
+    def __len__(self) -> int:
+        return len(self._store._fp_pba)
+
+
 class BlockStore:
-    """Content store with LBA mapping, fingerprint table and refcounts."""
+    """Content store with LBA mapping, fingerprint table and refcounts.
+
+    No per-fingerprint or per-block Python container lives in the store's
+    state: the maps are keyed and valued by ints (a fingerprint's several
+    PBAs are a dict of ints too), which CPython's cyclic garbage collector
+    never tracks, so a full collection costs the same however far the store
+    has aged.  Sets appear only for the few PBAs several LBAs share.
+    """
 
     def __init__(self, data_buffer_blocks: int = 4096):
-        self.lba_map: Dict[Tuple[int, int], int] = {}
-        self.lbas_of_pba: Dict[int, set] = {}  # reverse index for remapping
-        self.fp_table: Dict[int, List[int]] = {}
+        # the LBA mapping table: lba_key(stream, lba) -> PBA; ``lba_map``
+        # views it with (stream, lba) keys
+        self._lba_pba: Dict[int, int] = {}
+        # reverse index for remapping: PBA -> its one ``lba_key``, and for
+        # the few PBAs several LBAs share, PBA -> the set of their keys in
+        # a side map; read through ``lbas_of`` / ``pop_lbas``
+        self.lbas_of_pba: Dict[int, int] = {}
+        self._shared_lbas: Dict[int, set] = {}
+        # the fingerprint table: fp -> canonical PBA (the first written);
+        # ``fp_table`` views it as fp -> [PBA, ...]
+        self._fp_pba: Dict[int, int] = {}
         # membership index over fp_table's key set (batched probes for the
         # serving layer and the cluster; derived, rebuilt on restore)
         self.fp_index = FingerprintIndex()
-        # incremental duplicate-candidate set: fingerprints currently stored
-        # at >1 PBA.  Replaces the full fp_table scan per post-processing
-        # pass; ``duplicate_fingerprints`` sorts it so merge order is a
-        # deterministic function of store content (and thus identical
-        # between a live engine and one restored from its snapshot).
-        self._dup_fps: set = set()
+        # duplicate candidates: fingerprints currently stored at >1 PBA, each
+        # with its whole PBA row as an insertion-ordered dict of PBA -> None
+        # (canonical first; a dict of ints is one the collector does not
+        # track, a list always is).  Replaces the full fp_table scan per
+        # post-processing pass; ``duplicate_fingerprints`` sorts its keys so
+        # merge order is a deterministic function of store content (and thus
+        # identical between a live engine and one restored from its
+        # snapshot).
+        self._dup_fps: Dict[int, Dict[int, None]] = {}
         self.refcount: Dict[int, int] = {}
         self.fp_of_pba: Dict[int, int] = {}
         self.buffer = DLRUBuffer(data_buffer_blocks)
@@ -182,12 +260,7 @@ class BlockStore:
         """Write content to a fresh PBA (inline phase found no duplicate)."""
         pba = self._next_pba
         self._next_pba += 1
-        lst = self.fp_table.setdefault(fp, [])
-        lst.append(pba)
-        if len(lst) == 1:
-            self.fp_index.add(fp)
-        else:
-            self._dup_fps.add(fp)
+        self._add_pba(fp, pba)
         self.fp_of_pba[pba] = fp
         self.refcount[pba] = 0
         self._map(stream, lba, pba)
@@ -222,13 +295,13 @@ class BlockStore:
         pba = self._next_pba
         self._next_pba += 1
         self.fp_of_pba[pba] = fp
-        self.lba_map[(stream, lba)] = pba
+        self._lba_pba[(stream << 64) + lba] = pba
         self._staged_writes.append((fp, pba))
         return pba
 
     def stage_duplicate(self, stream: int, lba: int, pba: int) -> None:
         """Batched-path ``map_duplicate``; same no-overwrite precondition."""
-        self.lba_map[(stream, lba)] = pba
+        self._lba_pba[(stream << 64) + lba] = pba
         self._staged_dups.append(pba)
 
     def flush_staged(self) -> None:
@@ -237,18 +310,21 @@ class BlockStore:
         if not sw and not sd:
             return
         if sw:
-            ft = self.fp_table
+            ft = self._fp_pba
             ft_get = ft.get
+            dups = self._dup_fps
             fresh_fps = []
-            dup_add = self._dup_fps.add
             for fp, pba in sw:
-                lst = ft_get(fp)
-                if lst is None:
-                    ft[fp] = [pba]
+                canon = ft_get(fp)
+                if canon is None:
+                    ft[fp] = pba
                     fresh_fps.append(fp)
                 else:
-                    lst.append(pba)
-                    dup_add(fp)
+                    row = dups.get(fp)
+                    if row is None:
+                        dups[fp] = {canon: None, pba: None}
+                    else:
+                        row[pba] = None
             if fresh_fps:
                 self.fp_index.add_many(fresh_fps)
             # fresh PBAs start at refcount 1 (the write's own LBA mapping).
@@ -276,19 +352,102 @@ class BlockStore:
         """Rebuild the PBA -> LBA-keys reverse index after staged writes."""
         if not self._reverse_dirty:
             return
-        rev: Dict[int, set] = {}
-        for key, pba in self.lba_map.items():
-            s = rev.get(pba)
-            if s is None:
-                rev[pba] = {key}
-            else:
-                s.add(key)
-        self.lbas_of_pba = rev
+        single: Dict[int, int] = {}
+        shared: Dict[int, set] = {}
+        first = single.setdefault
+        for key, pba in self._lba_pba.items():
+            other = first(pba, key)
+            if other != key:
+                keys = shared.get(pba)
+                if keys is None:
+                    shared[pba] = {other, key}
+                else:
+                    keys.add(key)
+        for pba in shared:
+            del single[pba]
+        self.lbas_of_pba, self._shared_lbas = single, shared
         self._reverse_dirty = False
 
+    # -- reverse index: PBA -> lba_key ints --------------------------------------
+    def _rev_add(self, pba: int, key: int) -> None:
+        shared = self._shared_lbas.get(pba)
+        if shared is not None:
+            shared.add(key)
+            return
+        other = self.lbas_of_pba.setdefault(pba, key)
+        if other != key:  # a second reference: the PBA becomes shared
+            del self.lbas_of_pba[pba]
+            self._shared_lbas[pba] = {other, key}
+
+    def _rev_discard(self, pba: int, key: int) -> None:
+        shared = self._shared_lbas.get(pba)
+        if shared is None:
+            if self.lbas_of_pba.get(pba) == key:
+                del self.lbas_of_pba[pba]
+            return
+        shared.discard(key)
+        if len(shared) == 1:  # one reference left
+            del self._shared_lbas[pba]
+            self.lbas_of_pba[pba] = shared.pop()
+
+    def lbas_of(self, pba: int) -> List[int]:
+        """The ``lba_key``s the reverse index holds for ``pba``."""
+        shared = self._shared_lbas.get(pba)
+        if shared is not None:
+            return list(shared)
+        key = self.lbas_of_pba.get(pba)
+        return [] if key is None else [key]
+
+    def pop_lbas(self, pba: int) -> List[int]:
+        """``lbas_of(pba)``, dropping the PBA's reverse entry."""
+        shared = self._shared_lbas.pop(pba, None)
+        if shared is not None:
+            return list(shared)
+        key = self.lbas_of_pba.pop(pba, None)
+        return [] if key is None else [key]
+
+    def put_lbas(self, pba: int, keys: List[int]) -> None:
+        """Set ``pba``'s reverse entry to exactly ``keys`` (``lba_key``s)."""
+        self.pop_lbas(pba)
+        if len(keys) > 1:
+            self._shared_lbas[pba] = set(keys)
+        elif keys:
+            self.lbas_of_pba[pba] = keys[0]
+
+    # -- fingerprint table -------------------------------------------------------
+    def _add_pba(self, fp: int, pba: int) -> None:
+        """Append ``pba`` to ``fp``'s row (a new row enters the index)."""
+        canon = self._fp_pba.get(fp)
+        if canon is None:
+            self._fp_pba[fp] = pba
+            self.fp_index.add(fp)
+            return
+        row = self._dup_fps.get(fp)
+        if row is None:
+            self._dup_fps[fp] = {canon: None, pba: None}
+        else:
+            row[pba] = None
+
+    def _drop_pba(self, fp: int, pba: int) -> None:
+        """Remove ``pba`` from ``fp``'s row; an emptied row leaves the
+        table and the index, a row left with one PBA stops being a
+        duplicate candidate."""
+        row = self._dup_fps.get(fp)
+        if row is None:
+            if self._fp_pba.get(fp) == pba:
+                del self._fp_pba[fp]
+                self.fp_index.discard(fp)
+            return
+        if pba not in row:
+            return
+        del row[pba]
+        if len(row) == 1:
+            del self._dup_fps[fp]
+        self._fp_pba[fp] = next(iter(row))
+
     def _map(self, stream: int, lba: int, pba: int) -> None:
-        key = (stream, lba)
-        old = self.lba_map.get(key)
+        key = (stream << 64) + lba
+        old = self._lba_pba.get(key)
         if old == pba:
             return
         if old is not None:
@@ -298,10 +457,10 @@ class BlockStore:
             # discarded by the next rebuild.
             if self._reverse_dirty:
                 self._ensure_reverse()
-            self.lbas_of_pba.get(old, set()).discard(key)
+            self._rev_discard(old, key)
             self._unref(old)
-        self.lba_map[key] = pba
-        self.lbas_of_pba.setdefault(pba, set()).add(key)
+        self._lba_pba[key] = pba
+        self._rev_add(pba, key)
         self.refcount[pba] = self.refcount.get(pba, 0) + 1
         if lba >= self._lba_watermark.get(stream, 0):
             self._lba_watermark[stream] = lba + 1
@@ -314,13 +473,13 @@ class BlockStore:
         shard, the old owner must release its stale block.  Returns the
         unmapped PBA, or ``None`` if the key was not mapped.
         """
-        key = (stream, lba)
-        pba = self.lba_map.pop(key, None)
+        key = (stream << 64) + lba
+        pba = self._lba_pba.pop(key, None)
         if pba is None:
             return None
         if self._reverse_dirty:
             self._ensure_reverse()
-        self.lbas_of_pba.get(pba, set()).discard(key)
+        self._rev_discard(pba, key)
         self._unref(pba)
         return pba
 
@@ -338,19 +497,9 @@ class BlockStore:
         self._ever_freed = True
         fp = self.fp_of_pba.pop(pba, None)
         if fp is not None:
-            lst = self.fp_table.get(fp)
-            if lst:
-                try:
-                    lst.remove(pba)
-                except ValueError:
-                    pass
-                if len(lst) <= 1:
-                    self._dup_fps.discard(fp)
-                if not lst:
-                    del self.fp_table[fp]
-                    self.fp_index.discard(fp)
+            self._drop_pba(fp, pba)
         self.refcount.pop(pba, None)
-        self.lbas_of_pba.pop(pba, None)
+        self.pop_lbas(pba)
         self.buffer.invalidate(pba)
         self.live_blocks -= 1
         if self.deferred_reclaim:
@@ -371,7 +520,7 @@ class BlockStore:
 
     # -- read path ---------------------------------------------------------------
     def read(self, stream: int, lba: int) -> Optional[int]:
-        pba = self.lba_map.get((stream, lba))
+        pba = self._lba_pba.get((stream << 64) + lba)
         if pba is not None:
             self.buffer.access(pba)
         return pba
@@ -389,10 +538,10 @@ class BlockStore:
     def duplicate_fingerprints(self) -> List[int]:
         """Fingerprints stored at more than one PBA (inline misses).
 
-        Served from the incremental candidate set — no fp_table scan.  The
+        Served from the incremental candidate map — no fp_table scan.  The
         result is sorted so a budgeted merge pass picks the same victims on
-        a live store and on one restored from its snapshot (the set itself
-        carries no usable order across a restore).
+        a live store and on one restored from its snapshot (the map's own
+        order is its insertion history, which a restore does not keep).
         """
         return sorted(self._dup_fps)
 
@@ -401,20 +550,18 @@ class BlockStore:
 
         Returns the number of disk blocks reclaimed.
         """
-        pbas = self.fp_table.get(fp, [])
-        if len(pbas) <= 1:
+        pbas = self._dup_fps.get(fp)
+        if pbas is None:
             return 0
         self._ensure_reverse()
-        canonical, extras = pbas[0], list(pbas[1:])
-        canon_keys = self.lbas_of_pba.setdefault(canonical, set())
+        canonical, *extras = pbas
         reclaimed = 0
         for p in extras:
-            for key in list(self.lbas_of_pba.get(p, ())):
-                self.lba_map[key] = canonical
-                canon_keys.add(key)
+            for key in self.pop_lbas(p):
+                self._lba_pba[key] = canonical
+                self._rev_add(canonical, key)
                 self.refcount[canonical] = self.refcount.get(canonical, 0) + 1
                 self.refcount[p] -= 1
-            self.lbas_of_pba[p] = set()
             if self.refcount.get(p, 0) <= 0:
                 self._free(p)
                 reclaimed += 1
@@ -474,13 +621,18 @@ class BlockStore:
         """Move one live block's identity from slot ``old`` to ``new``."""
         fp = self.fp_of_pba.pop(old)
         self.fp_of_pba[new] = fp
-        lst = self.fp_table[fp]
-        lst[lst.index(old)] = new  # in place: canonical order is positional
+        row = self._dup_fps.get(fp)
+        if row is None:
+            self._fp_pba[fp] = new
+        else:
+            # in place: canonical order is positional
+            self._dup_fps[fp] = row = {new if p == old else p: None for p in row}
+            self._fp_pba[fp] = next(iter(row))
         self.refcount[new] = self.refcount.pop(old)
-        keys = self.lbas_of_pba.pop(old, set())
+        keys = self.pop_lbas(old)
         for key in keys:
-            self.lba_map[key] = new
-        self.lbas_of_pba[new] = keys
+            self._lba_pba[key] = new
+        self.put_lbas(new, keys)
         self.buffer.invalidate(old)
         self.relocated_blocks += 1
         if self.on_relocate is not None:
@@ -490,20 +642,16 @@ class BlockStore:
     def extract_fp(self, fp: int) -> Optional[List[int]]:
         """Pop ``fp``'s whole fingerprint-table row (resharding moves it to
         another shard's store); keeps the index and candidate set coherent."""
-        pbas = self.fp_table.pop(fp, None)
-        if pbas is not None:
-            self.fp_index.discard(fp)
-            self._dup_fps.discard(fp)
-        return pbas
+        canon = self._fp_pba.pop(fp, None)
+        if canon is None:
+            return None
+        self.fp_index.discard(fp)
+        return list(self._dup_fps.pop(fp, None) or (canon,))
 
     def absorb_fp(self, fp: int, pbas: List[int]) -> None:
         """Append a migrated row to ``fp``'s fingerprint-table entry."""
-        lst = self.fp_table.setdefault(fp, [])
-        lst.extend(pbas)
-        if lst:
-            self.fp_index.add(fp)
-        if len(lst) > 1:
-            self._dup_fps.add(fp)
+        for pba in pbas:
+            self._add_pba(fp, pba)
 
     # -- snapshot/restore ----------------------------------------------------------
     def snapshot(self) -> dict:
@@ -518,8 +666,9 @@ class BlockStore:
         """
         self.flush_staged()
         return {
-            "lba_map": kv3(self.lba_map),
-            "fp_table": [[fp, list(pbas)] for fp, pbas in self.fp_table.items()],
+            "lba_map": [[*lba_of_key(k), p] for k, p in self._lba_pba.items()],
+            "fp_table": [[fp, list(self._dup_fps.get(fp) or (pba,))]
+                         for fp, pba in self._fp_pba.items()],
             "refcount": pairs(self.refcount),
             "fp_of_pba": pairs(self.fp_of_pba),
             "next_pba": self._next_pba,
@@ -545,11 +694,15 @@ class BlockStore:
         }
 
     def load_snapshot(self, tree: dict) -> None:
-        self.lba_map = from_kv3(tree["lba_map"])
-        self.fp_table = {int(fp): [int(p) for p in pbas] for fp, pbas in tree["fp_table"]}
+        self._lba_pba = {lba_key(int(s), int(lba)): int(p) for s, lba, p in tree["lba_map"]}
+        self._fp_pba, self._dup_fps = {}, {}
+        for fp, pbas in tree["fp_table"]:
+            if pbas:
+                self._fp_pba[int(fp)] = int(pbas[0])
+            if len(pbas) > 1:
+                self._dup_fps[int(fp)] = dict.fromkeys(int(p) for p in pbas)
         # derived structures: rebuilt from the serialized table, never stored
-        self.fp_index = FingerprintIndex(self.fp_table)
-        self._dup_fps = {fp for fp, pbas in self.fp_table.items() if len(pbas) > 1}
+        self.fp_index = FingerprintIndex(self._fp_pba)
         self.refcount = from_pairs(tree["refcount"], value=int)
         self.fp_of_pba = from_pairs(tree["fp_of_pba"], value=int)
         self._next_pba = int(tree["next_pba"])
@@ -562,7 +715,7 @@ class BlockStore:
         self.buffer.load_snapshot(tree["buffer"])
         self._staged_writes = []
         self._staged_dups = []
-        self.lbas_of_pba = {}
+        self.lbas_of_pba, self._shared_lbas = {}, {}
         self._reverse_dirty = True  # rebuilt lazily from lba_map
         gc = tree.get("gc") or {}
         self.gc_epoch = int(gc.get("epoch", 0))
@@ -573,21 +726,31 @@ class BlockStore:
         self._epoch_pins = {}
 
     # -- invariants (used by property tests) --------------------------------------
+    @property
+    def lba_map(self) -> LbaMap:
+        """The LBA mapping table as ``(stream, lba) -> PBA`` (read-only)."""
+        return LbaMap(self)
+
+    @property
+    def fp_table(self) -> FpTable:
+        """The fingerprint table as ``fp -> [PBA, ...]`` (read-only)."""
+        return FpTable(self)
+
     def lookup_fp(self, fp: int) -> Optional[int]:
-        pbas = self.fp_table.get(fp)
-        return pbas[0] if pbas else None
+        return self._fp_pba.get(fp)
 
     def unique_fingerprints(self) -> int:
-        return len(self.fp_table)
+        return len(self._fp_pba)
 
     def check_consistency(self) -> None:
         """Raise AssertionError if internal tables disagree."""
         assert not self._staged_writes and not self._staged_dups, "unflushed staged writes"
         self._ensure_reverse()
-        assert set(self.fp_index) == set(self.fp_table), "fp_index drifted from fp_table"
+        assert set(self.fp_index) == set(self._fp_pba), "fp_index drifted from fp_table"
         self.fp_index.check_consistency()
-        derived_dups = {fp for fp, pbas in self.fp_table.items() if len(pbas) > 1}
-        assert self._dup_fps == derived_dups, "duplicate-candidate set drifted"
+        for fp, pbas in self._dup_fps.items():
+            assert len(pbas) > 1, f"duplicate candidate {fp} holds {pbas}"
+            assert self._fp_pba.get(fp) == next(iter(pbas)), f"canonical PBA of {fp} drifted"
         live = set()
         for fp, pbas in self.fp_table.items():
             assert len(pbas) == len(set(pbas)), f"dup PBAs for fp {fp}"
@@ -595,10 +758,12 @@ class BlockStore:
                 assert self.fp_of_pba.get(p) == fp
                 live.add(p)
         assert len(live) == self.live_blocks, (len(live), self.live_blocks)
+        assert all(len(keys) > 1 for keys in self._shared_lbas.values()), "unshared PBA in sets"
+        assert not self._shared_lbas.keys() & self.lbas_of_pba.keys(), "PBA both shared and not"
         refs: Dict[int, int] = {}
-        for key, pba in self.lba_map.items():
+        for key, pba in self._lba_pba.items():
             assert pba in live, f"LBA maps to freed PBA {pba}"
-            assert key in self.lbas_of_pba.get(pba, ()), f"reverse index missing {key}"
+            assert key in self.lbas_of(pba), f"reverse index missing {lba_of_key(key)}"
             refs[pba] = refs.get(pba, 0) + 1
         for p in live:
             assert self.refcount.get(p, 0) == refs.get(p, 0), (

@@ -15,9 +15,17 @@ running, a batch of 32,768 writes opens about a hundred.
 
 ``SPANS`` names every span with its layer; the benchmark's reader
 (``bench/spans.py``) and the tests hold the program to it.
+
+``trace_gc()`` records each of CPython's cyclic garbage collections as a
+``dedup.gc.collect`` span (``generation``, and ``collected`` at its end) on
+the thread it interrupts, through one ``gc.callbacks`` hook installed once
+per process.  A collection stops every thread, so without its own span it
+lands as self time of whichever span happened to be open.
 """
 
 from __future__ import annotations
+
+import gc
 
 PREFIX = "dedup."
 
@@ -47,6 +55,8 @@ SPANS = {
     "fp_index.route_keys": "fp-index kernels",  # _route_keys
     "fp_index.put": "fp-index kernels",  # key transfer and dispatch (keys, slots)
     "fp_index.fetch": "fp-index kernels",  # read-back, waiting for the device
+    # trace_gc's hook: one of CPython's cyclic collections (generation, collected)
+    "gc.collect": "cluster and engines",
 }
 
 # engine.boundary kinds (a bit each: both can fall on one record)
@@ -60,3 +70,26 @@ def span(name: str, **stats):
     from jax.profiler import TraceAnnotation
 
     return TraceAnnotation(PREFIX + name, **stats)
+
+
+_gc_span = None  # the open dedup.gc.collect span (collections never overlap)
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    global _gc_span
+    if phase == "start":
+        _gc_span = span("gc.collect", generation=info["generation"])
+        _gc_span.__enter__()
+    elif _gc_span is not None:
+        _gc_span.set_metadata(collected=info["collected"])
+        _gc_span.__exit__(None, None, None)
+        _gc_span = None
+
+
+def trace_gc() -> None:
+    """Install the ``gc.collect`` span hook; installing it again is a no-op.
+    JAX's profiler is imported here, so the hook itself never imports."""
+    import jax.profiler  # noqa: F401
+
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)

@@ -8,6 +8,9 @@ Every test here drives the real batched entry points with ``small_batch=0``
 so the device-layout table is exercised, not the host-set shortcut.
 """
 
+import gc
+from collections.abc import Set
+
 import numpy as np
 import pytest
 
@@ -135,10 +138,12 @@ def test_scalar_and_batched_paths_interleave():
 
 
 def test_set_api_compatibility():
-    """The index is a drop-in ``set`` for host-side consumers (snapshots
-    sort it, resharding unions and discards it, harnesses iterate it)."""
+    """The index has the ``set`` API host-side consumers use (snapshots
+    sort it, resharding unions and discards it, harnesses iterate it),
+    over membership the garbage collector does not track."""
     idx = FingerprintIndex([3, 1, 2])
-    assert isinstance(idx, set)
+    assert isinstance(idx, Set) and not isinstance(idx, set)
+    assert not gc.is_tracked(idx._keys)
     assert sorted(idx) == [1, 2, 3]
     assert len(idx) == 3 and 2 in idx
     plain = set()
@@ -157,6 +162,35 @@ def test_set_api_compatibility():
     idx.check_consistency()
     idx.clear()
     assert len(idx) == 0
+    idx.check_consistency()
+
+
+SET_API = [
+    ("in", lambda idx: (2 in idx, 7 in idx, np.uint64(3) in idx), (True, False, True)),
+    ("len", len, 3),
+    ("iteration", lambda idx: sorted(k for k in idx), [1, 2, 3]),
+    ("sorted", sorted, [1, 2, 3]),
+    ("union_with_set", lambda idx: (idx | {4}, {4} | idx), ({1, 2, 3, 4}, {1, 2, 3, 4})),
+    ("difference_with_set", lambda idx: (idx - {1}, {1, 5} - idx), ({2, 3}, {5})),
+    ("equality", lambda idx: (idx == {1, 2, 3}, {1, 2, 3} == idx, idx == {1}), (True, True, False)),
+    ("ior_into_plain_set", lambda idx: set().__ior__(set(idx)) | idx, {1, 2, 3}),
+]
+
+
+@pytest.mark.parametrize("backend", ["numpy", "pallas"])
+@pytest.mark.parametrize("name,op,want", SET_API, ids=[c[0] for c in SET_API])
+def test_read_only_set_api(name, op, want, backend):
+    """Each read-only set operation a consumer uses, after scalar and batched
+    mutations (so the answer comes from the authoritative dict, not a copy);
+    results built by the binary operators are plain sets."""
+    idx = FingerprintIndex([3, 9], backend=backend, small_batch=0)
+    idx.add_many(np.array([1, 2, 9], dtype=np.uint64))
+    idx.discard(9)
+    got = op(idx)
+    assert got == want
+    for part in got if isinstance(got, tuple) else (got,):
+        assert not isinstance(part, FingerprintIndex)
+    assert not gc.is_tracked(idx._keys)
     idx.check_consistency()
 
 

@@ -4,13 +4,15 @@ A profiler trace over a tiny 4-shard ``ShardedCluster`` behind
 ``AsyncDedupFrontend`` (shard executor on, indexes on the Pallas backend in
 interpret mode) must hold every span ``repro.obs`` names, with the shard
 spans on the worker threads carrying the cluster's batch number, and a
-bounded number of spans per batch.  ``FingerprintIndex.table_stats()``'s
-launch counters are checked against the launches themselves.
+bounded number of spans per batch, and a forced full garbage collection as a
+``gc.collect`` span.  ``FingerprintIndex.table_stats()``'s launch counters
+are checked against the launches themselves.
 """
 
 from __future__ import annotations
 
 import asyncio
+import gc
 import glob
 import os
 from collections import Counter
@@ -85,6 +87,7 @@ def served(tmp_path_factory):
         jax.profiler.start_trace(trace_dir)
         try:
             fe = asyncio.run(_serve(cluster, served))
+            gc.collect(2)  # a full collection inside the trace, whatever serving allocated
         finally:
             jax.profiler.stop_trace()
     finally:
@@ -96,6 +99,29 @@ def test_every_span_appears_and_none_outside_the_table(served):
     _, _, threads = served
     seen = {name for _, spans in threads for name, *_ in spans}
     assert seen == set(obs.SPANS)
+
+
+def test_forced_full_collection_lands_as_a_gc_span(served):
+    _, _, threads = served
+    assert obs.SPANS["gc.collect"] == "cluster and engines"
+    gcs = [st for _, spans in threads for name, _, _, st in spans if name == "gc.collect"]
+    full = [st for st in gcs if st["generation"] == 2]
+    assert full and all(st["collected"] >= 0 for st in full)
+    assert {st["generation"] for st in gcs} <= {0, 1, 2}
+
+
+def test_building_clusters_installs_the_gc_hook_once():
+    ShardedCluster(num_shards=2, seed=0)
+    ShardedCluster(num_shards=2, seed=1)
+    assert gc.callbacks.count(obs._on_gc) == 1
+    obs.trace_gc()
+    assert gc.callbacks.count(obs._on_gc) == 1
+
+
+def test_gc_hook_without_a_trace_records_nothing_and_closes_its_span():
+    obs.trace_gc()
+    gc.collect(2)
+    assert obs._gc_span is None  # every start met its stop
 
 
 def test_shard_spans_run_on_worker_threads_with_the_cluster_batch(served):
