@@ -1717,7 +1717,6 @@ class ShardedCluster:
             if not fps:
                 continue
             owners = new_ring.shard_of_many(np.asarray(fps, dtype=np.uint64))
-            src.store._ensure_reverse()
             src_targets = set()
             for fp, t in zip(fps, owners.tolist()):
                 if t == s:
@@ -2008,7 +2007,7 @@ def _migrate_fp(src, dst, fp: int, directory: Dict[int, int], t: int):
     if not pbas:
         return 0, moved_cache
     for pba in pbas:
-        keys = src_store.pop_lbas(pba)
+        keys = src_store.release_lbas(pba)
         dst_store.fp_of_pba[pba] = fp
         dst_store.refcount[pba] = src_store.refcount.pop(pba)
         del src_store.fp_of_pba[pba]
@@ -2016,14 +2015,12 @@ def _migrate_fp(src, dst, fp: int, directory: Dict[int, int], t: int):
         dst_store.live_blocks += 1
         src_store.buffer.invalidate(pba)
         for key in keys:
-            del src_store._lba_pba[key]
             dst_store._lba_pba[key] = pba
             stream, lba = lba_of_key(key)
             directory[(stream << _LBA_BITS) + lba] = t
             if lba >= dst_store._lba_watermark.get(stream, 0):
                 dst_store._lba_watermark[stream] = lba + 1
-        if not dst_store._reverse_dirty:
-            dst_store.put_lbas(pba, keys)
+        # the destination's reverse index takes the keys with its next delta
     # absorb keeps the destination's fingerprint index and duplicate-
     # candidate set coherent (a migrated fp landing on a shard that already
     # holds it is exactly the cross-shard duplicate reconcile later merges)

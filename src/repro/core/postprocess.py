@@ -16,6 +16,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Dict, Optional
 
+from .. import obs
 from .store import BlockStore
 
 
@@ -56,16 +57,21 @@ class PostProcessEngine:
         """
         merged: Dict[int, int] = {}
         dups = self.store.duplicate_fingerprints()
-        for done, fp in enumerate(dups):
-            if max_merges is not None and done >= max_merges:
-                break
-            reclaimed = self.store.merge_fingerprint(fp)
-            self.metrics.merges += 1
-            self.metrics.blocks_reclaimed += reclaimed
-            canonical = self.store.lookup_fp(fp)
-            if canonical is not None:
-                merged[fp] = canonical
-        self.metrics.passes += 1
+        m = self.metrics
+        merges0, reclaimed0 = m.merges, m.blocks_reclaimed
+        with obs.span("post.run", backlog=len(dups)) as span:
+            for done, fp in enumerate(dups):
+                if max_merges is not None and done >= max_merges:
+                    break
+                reclaimed = self.store.merge_fingerprint(fp)
+                m.merges += 1
+                m.blocks_reclaimed += reclaimed
+                canonical = self.store.lookup_fp(fp)
+                if canonical is not None:
+                    merged[fp] = canonical
+            m.passes += 1
+            span.set_metadata(merges=m.merges - merges0,
+                              reclaimed=m.blocks_reclaimed - reclaimed0)
         return merged
 
     def run_to_exact(self) -> Dict[int, int]:

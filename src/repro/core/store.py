@@ -22,8 +22,10 @@ from __future__ import annotations
 import threading
 from collections import OrderedDict
 from collections.abc import Mapping
+from itertools import islice
 from typing import Callable, Dict, List, Optional, Tuple
 
+from .. import obs
 from .fp_index import FingerprintIndex
 from .statetree import from_pairs, pairs
 
@@ -137,9 +139,16 @@ class BlockStore:
         self._lba_pba: Dict[int, int] = {}
         # reverse index for remapping: PBA -> its one ``lba_key``, and for
         # the few PBAs several LBAs share, PBA -> the set of their keys in
-        # a side map; read through ``lbas_of`` / ``pop_lbas``
+        # a side map; read through ``lbas_of`` / ``pop_lbas``.  It covers the
+        # first ``_rev_synced`` keys of ``_lba_pba`` in insertion order; the
+        # staged path only appends keys, so the rest are the keys staged
+        # since ``_ensure_reverse`` last ran, and that is all it walks
         self.lbas_of_pba: Dict[int, int] = {}
         self._shared_lbas: Dict[int, set] = {}
+        self._rev_synced = 0
+        # keys ``_ensure_reverse`` has walked (a counter, not state: outside
+        # the snapshot)
+        self.reverse_keys_walked = 0
         # the fingerprint table: fp -> canonical PBA (the first written);
         # ``fp_table`` views it as fp -> [PBA, ...]
         self._fp_pba: Dict[int, int] = {}
@@ -165,7 +174,6 @@ class BlockStore:
         # staged columnar write path (batched replay): see stage_new_block
         self._staged_writes: List[Tuple[int, int]] = []  # (fp, pba)
         self._staged_dups: List[int] = []  # pba
-        self._reverse_dirty = False
         # per-stream LBA watermark: strict upper bound over every LBA this
         # store has mapped (or that the batched driver has certified for
         # staging).  Lets the driver prove key-freshness without probing
@@ -284,10 +292,10 @@ class BlockStore:
     # may read (``lba_map`` for reads, ``fp_of_pba`` for the run-decision
     # TOCTOU guard) and a *deferred* part (``fp_table``/``refcount``/capacity
     # counters) applied in one pass by ``flush_staged`` before any external
-    # observer (post-processing, reports) can look.  The reverse LBA index is
-    # rebuilt lazily from ``lba_map`` the next time remapping needs it, and
-    # the D-LRU buffer — whose state feeds no report — is modeled only on the
-    # per-record path.
+    # observer (post-processing, reports) can look.  The reverse LBA index
+    # takes the staged keys the next time remapping needs it
+    # (``_ensure_reverse``), and the D-LRU buffer — whose state feeds no
+    # report — is modeled only on the per-record path.
 
     def stage_new_block(self, stream: int, lba: int, fp: int) -> int:
         """Batched-path ``write_new_block``; caller guarantees (stream, lba)
@@ -344,29 +352,41 @@ class BlockStore:
             rc_get = rc.get
             for pba in sd:
                 rc[pba] = rc_get(pba, 0) + 1
-        self._reverse_dirty = True
         sw.clear()
         sd.clear()
 
     def _ensure_reverse(self) -> None:
-        """Rebuild the PBA -> LBA-keys reverse index after staged writes."""
-        if not self._reverse_dirty:
+        """Bring the PBA -> LBA-keys reverse index up to date: add the keys
+        ``_lba_pba`` gained since the last call, read off the end of its
+        insertion order, so the walk is as long as the writes staged since
+        then, whatever the volume maps.
+
+        Every other change to ``_lba_pba`` keeps the index current itself:
+        an overwrite, a merge, a relocation or a migration changes a key's
+        PBA only after this call and updates its reverse entry, and a key
+        removed from the map (``unmap``, ``release_lbas``) takes
+        ``_rev_synced`` down by one (removals happen only after this call).
+        Adding a key the index already holds changes nothing, so walking a
+        key twice is harmless."""
+        lm = self._lba_pba
+        n = len(lm) - self._rev_synced
+        if n <= 0:
             return
-        single: Dict[int, int] = {}
-        shared: Dict[int, set] = {}
-        first = single.setdefault
-        for key, pba in self._lba_pba.items():
-            other = first(pba, key)
-            if other != key:
+        with obs.span("store.reverse", keys=n):
+            single, shared = self.lbas_of_pba, self._shared_lbas
+            first = single.setdefault
+            tail = list(islice(reversed(lm.items()), n))
+            for key, pba in reversed(tail):
                 keys = shared.get(pba)
-                if keys is None:
-                    shared[pba] = {other, key}
-                else:
+                if keys is not None:
                     keys.add(key)
-        for pba in shared:
-            del single[pba]
-        self.lbas_of_pba, self._shared_lbas = single, shared
-        self._reverse_dirty = False
+                    continue
+                other = first(pba, key)
+                if other != key:  # a second reference: the PBA becomes shared
+                    del single[pba]
+                    shared[pba] = {other, key}
+            self._rev_synced = len(lm)
+            self.reverse_keys_walked += n
 
     # -- reverse index: PBA -> lba_key ints --------------------------------------
     def _rev_add(self, pba: int, key: int) -> None:
@@ -405,6 +425,17 @@ class BlockStore:
             return list(shared)
         key = self.lbas_of_pba.pop(pba, None)
         return [] if key is None else [key]
+
+    def release_lbas(self, pba: int) -> List[int]:
+        """Take ``pba``'s keys out of the LBA map and the reverse index (its
+        block leaves this store, as in resharding); returns the keys."""
+        self._ensure_reverse()
+        keys = self.pop_lbas(pba)
+        lm = self._lba_pba
+        for key in keys:
+            del lm[key]
+        self._rev_synced -= len(keys)
+        return keys
 
     def put_lbas(self, pba: int, keys: List[int]) -> None:
         """Set ``pba``'s reverse entry to exactly ``keys`` (``lba_key``s)."""
@@ -447,20 +478,20 @@ class BlockStore:
 
     def _map(self, stream: int, lba: int, pba: int) -> None:
         key = (stream << 64) + lba
-        old = self._lba_pba.get(key)
+        lm = self._lba_pba
+        old = lm.get(key)
         if old == pba:
             return
         if old is not None:
-            # overwrite: the reverse index is about to be read/mutated, so a
-            # stale (post-staged-write) index must be rebuilt first.  Fresh
-            # mappings never read it — eager adds to a stale index are
-            # discarded by the next rebuild.
-            if self._reverse_dirty:
-                self._ensure_reverse()
+            # overwrite: the reverse index is about to be read and changed,
+            # so it takes the staged keys first
+            self._ensure_reverse()
             self._rev_discard(old, key)
             self._unref(old)
-        self._lba_pba[key] = pba
+        lm[key] = pba
         self._rev_add(pba, key)
+        if old is None and self._rev_synced == len(lm) - 1:
+            self._rev_synced += 1  # nothing staged since the last call: covered
         self.refcount[pba] = self.refcount.get(pba, 0) + 1
         if lba >= self._lba_watermark.get(stream, 0):
             self._lba_watermark[stream] = lba + 1
@@ -474,11 +505,11 @@ class BlockStore:
         unmapped PBA, or ``None`` if the key was not mapped.
         """
         key = (stream << 64) + lba
-        pba = self._lba_pba.pop(key, None)
-        if pba is None:
+        if key not in self._lba_pba:
             return None
-        if self._reverse_dirty:
-            self._ensure_reverse()
+        self._ensure_reverse()
+        pba = self._lba_pba.pop(key)
+        self._rev_synced -= 1
         self._rev_discard(pba, key)
         self._unref(pba)
         return pba
@@ -716,7 +747,7 @@ class BlockStore:
         self._staged_writes = []
         self._staged_dups = []
         self.lbas_of_pba, self._shared_lbas = {}, {}
-        self._reverse_dirty = True  # rebuilt lazily from lba_map
+        self._rev_synced = 0  # the whole map is the next delta
         gc = tree.get("gc") or {}
         self.gc_epoch = int(gc.get("epoch", 0))
         self._limbo = [(int(e), int(p)) for e, p in gc.get("limbo", [])]

@@ -46,6 +46,11 @@ SPANS = {
     "engine.prepass": "cluster and engines",  # probes, certify, accumulation, consumes
     "engine.decide": "cluster and engines",  # residual loop and staged store flush
     "engine.boundary": "cluster and engines",  # scalar trigger record (kind: 1 interval, 2 post)
+    # core/postprocess.py: one pass (backlog = duplicate rows at its start;
+    # merges, reclaimed); core/store.py: the reverse index takes the keys
+    # staged since it last ran (keys)
+    "post.run": "post-processing",
+    "store.reverse": "post-processing",
     # core/fp_index.py; keys = keys probed, inserted, removed or folded
     "fp_index.probe": "membership index",
     "fp_index.insert": "membership index",  # placed = keys the launch placed

@@ -32,6 +32,7 @@ from repro.serving.frontend import AsyncDedupFrontend
 
 BATCH = 1024
 BATCHES = 6
+POST_PERIOD = 512
 
 
 @pytest.fixture
@@ -77,9 +78,11 @@ def served(tmp_path_factory):
     mp.setitem(FingerprintIndex.__init__.__kwdefaults__, "backend", "pallas")
     mp.setitem(FingerprintIndex.__init__.__kwdefaults__, "small_batch", 32)
     try:
-        # a small cache: evictions tombstone the cache index, and the
-        # estimator's interval (256 writes) ends inside the sub-batches
-        cluster = ShardedCluster(num_shards=4, seed=0, cache_entries=256)
+        # a small cache: evictions tombstone the cache index, the
+        # estimator's interval (256 writes) ends inside the sub-batches, and
+        # each shard runs a post-processing pass every 512 of its writes
+        cluster = ShardedCluster(num_shards=4, seed=0, cache_entries=256,
+                                 postprocess_period=POST_PERIOD)
         cluster.min_parallel_batch = 64
         cluster.ingest_batched(writes[:4096])
         served = writes[4096:4096 + BATCH * BATCHES]
@@ -160,6 +163,44 @@ def test_spans_per_batch_are_bounded_not_per_write(served):
     assert boundary and all(st["kind"] in (obs.BOUNDARY_INTERVAL, obs.BOUNDARY_POST,
                                            obs.BOUNDARY_INTERVAL | obs.BOUNDARY_POST)
                             for st in boundary)
+
+
+def test_post_processing_passes_land_as_spans_inside_the_shard_calls(served):
+    cluster, _, threads = served
+    assert obs.SPANS["post.run"] == obs.SPANS["store.reverse"] == "post-processing"
+    for _, spans in threads:
+        shard_calls = [(a, b) for name, a, b, _ in spans if name == "shard.write_batch"]
+        for name, a, b, st in spans:
+            if name == "post.run":
+                # a pass runs at the scalar record that reaches the period
+                assert any(lo <= a and b <= hi for lo, hi in shard_calls)
+                assert 0 <= st["merges"] <= st["backlog"] and st["reclaimed"] >= 0
+    runs = [st for _, spans in threads for name, _, _, st in spans if name == "post.run"]
+    # the served batches give each shard about BATCH * BATCHES / 4 writes
+    assert len(runs) >= 4 * (BATCH * BATCHES // 4 // POST_PERIOD - 1)
+    assert sum(st["merges"] for st in runs) > 0
+
+
+def test_reverse_index_span_walks_the_keys_staged_since_the_last_pass(served):
+    cluster, _, threads = served
+    walks = [st["keys"] for _, spans in threads for name, _, _, st in spans
+             if name == "store.reverse"]
+    assert walks and all(0 < k <= 2 * POST_PERIOD for k in walks)
+    # the counter adds up every walk, the ingest's included
+    assert sum(e.store.reverse_keys_walked for e in cluster.shards) >= sum(walks)
+
+
+def test_reverse_keys_counter_stays_out_of_the_snapshot():
+    cluster = ShardedCluster(num_shards=1, seed=0, cache_entries=64, postprocess_period=64)
+    trace, _ = generate_workload("C", total_requests=2_000, seed=4)
+    cluster.ingest_batched(trace)
+    store = cluster.shards[0].store
+    assert store.reverse_keys_walked > 0
+    tree = store.snapshot()
+    assert "reverse_keys_walked" not in str(tree)
+    again = type(store)()
+    again.load_snapshot(tree)
+    assert again.reverse_keys_walked == 0
 
 
 def test_frontend_counters_add_up(served):

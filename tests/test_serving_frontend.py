@@ -75,6 +75,42 @@ def test_per_tenant_counts_match_single_stream_replay():
         assert stats["tenants"][t]["deduped"] == int(flags[mask].sum())
 
 
+@pytest.mark.parametrize("num_shards", [1, 2, 4])
+def test_postprocess_period_on_the_served_path_matches_the_scalar_oracle(num_shards):
+    """Workload C with the exact phase running every 256 writes per shard:
+    served through the front end, the cluster's report is bit-exact against
+    the same interleaving replayed record by record through the scalar
+    oracle, shard by shard (``HPDedup.replay``)."""
+    from repro.core.fingerprint import OP_WRITE, TRACE_DTYPE
+
+    tenants = _tenant_columns(total=4_000, seed=9, workload="C")
+
+    def make():
+        return ShardedCluster(num_shards=num_shards, cache_entries=256, postprocess_period=256)
+
+    async def run():
+        engine = make()
+        fe = AsyncDedupFrontend(engine, max_batch=256, max_delay=0.001, max_pending=512,
+                                record_trace=True)
+        await _drive(fe, tenants)
+        await fe.close()
+        return engine, fe
+
+    engine, fe = asyncio.run(run())
+    rep = engine.finish()
+    assert rep.post.passes > 3 * num_shards  # passes ran on the served path, not only at finish
+    t_col, l_col, f_col = fe.executed_trace()
+    trace = np.zeros(t_col.size, dtype=TRACE_DTYPE)
+    trace["ts"] = np.arange(t_col.size)
+    trace["stream"], trace["op"], trace["lba"], trace["fp"] = t_col, OP_WRITE, l_col, f_col
+    oracle = make()
+    oracle.replay(trace)
+    assert oracle.finish() == rep
+    for a, b in zip(oracle.shard_reports, engine.shard_reports):
+        assert a == b
+    engine.check_consistency()
+
+
 def test_frontend_over_single_engine_and_kv_server():
     tenants = _tenant_columns(total=2_000, seed=8)
 
