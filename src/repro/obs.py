@@ -32,9 +32,9 @@ PREFIX = "dedup."
 SPANS = {
     # serving/frontend.py; batch = the front end's batch number in closing order
     "frontend.fill": "front end",  # event loop, first buffered write to close
-    "frontend.close": "front end",  # _flush: list -> arrays, hand-off (keys)
+    "frontend.close": "front end",  # _flush: list -> arrays, hand-off (keys, slow)
     "frontend.execute": "front end",  # engine thread, around write_batch (queue_us)
-    "frontend.ack": "front end",  # _on_batch_done's delivery loop (keys)
+    "frontend.ack": "front end",  # _on_batch_done: per-tenant accounting, release, futures (keys)
     # core/cluster.py; batch = the cluster's write_batch call number
     "cluster.write_batch": "cluster and engines",  # coordinator (keys)
     "cluster.route": "cluster and engines",  # _route_chunk

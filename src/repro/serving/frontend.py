@@ -23,10 +23,19 @@ by an asyncio front end that
   the batch pipeline (the front-end analogue of the cache's own
   prioritized admission), with a floor so nobody starves;
 * **exerts backpressure** — a global ``max_pending`` bound on buffered +
-  in-flight writes; producers ``await`` when the pipeline is full;
+  in-flight writes; producers ``await`` when the pipeline is full, served
+  in arrival order;
 * **supports live ``resize()`` under traffic** — the elastic-resharding
   protocol from PR 3 runs on the engine executor thread, serialized behind
   the batches already queued, while new writes keep buffering.
+
+``write`` is a plain call.  While the pipeline has room, nobody waits on
+backpressure and the tenant is under its admission cap, it buffers the write
+and returns the write's future: no coroutine, no lock.  Otherwise it returns
+the coroutine of the slow path, which waits on the cap, then on
+backpressure, then buffers.  A batch is acknowledged per tenant in bulk
+(counts, flags and latencies as arrays); only resolving the futures is left
+per write.
 
 Determinism contract: the executed interleaving (the concatenation of
 batches in execution order) replayed through a fresh identically-configured
@@ -37,10 +46,11 @@ tests/test_serving_frontend.py via ``executed_trace``.
 from __future__ import annotations
 
 import asyncio
+import collections
 import dataclasses
 import time
 from concurrent.futures import ThreadPoolExecutor
-from typing import Dict, List, Optional, Tuple
+from typing import Awaitable, Deque, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -49,18 +59,17 @@ from .. import obs
 
 @dataclasses.dataclass
 class TenantQoS:
-    """Per-tenant serving statistics (latencies in seconds)."""
+    """Per-tenant serving statistics (latencies in seconds, one array per
+    acknowledged batch that held the tenant's writes)."""
 
     submitted: int = 0
     completed: int = 0
     deduped: int = 0
     throttled: int = 0  # writes that waited on the admission cap
-    latencies: List[float] = dataclasses.field(default_factory=list)
+    latencies: List[np.ndarray] = dataclasses.field(default_factory=list)
 
-    def percentile_ms(self, q: float) -> float:
-        if not self.latencies:
-            return 0.0
-        return float(np.percentile(np.asarray(self.latencies), q) * 1e3)
+    def latency_samples(self) -> np.ndarray:
+        return np.concatenate(self.latencies) if self.latencies else np.zeros(0)
 
 
 class AsyncDedupFrontend:
@@ -110,8 +119,14 @@ class AsyncDedupFrontend:
         self._buf_fps: List[int] = []
         self._buf_futs: List[asyncio.Future] = []
         self._buf_t0: List[float] = []
+        self._buf_slow = 0  # writes in the open batch that took the slow path
         self._timer: Optional[asyncio.TimerHandle] = None
-        self._sem = asyncio.Semaphore(self.max_pending)
+        # backpressure: slots held (buffered, in flight, or handed to a
+        # waiter that has not buffered yet) and the FIFO of waiters
+        self._pending = 0
+        self._waiters: Deque[asyncio.Future] = collections.deque()
+        self._granted = 0  # waiters handed a slot that have not resumed yet
+        self.slow_writes = 0
         self._drained = asyncio.Event()  # pulsed after every batch completes
         self._inflight: Dict[int, int] = {}
         self._next_lba: Dict[int, int] = {}
@@ -220,7 +235,7 @@ class AsyncDedupFrontend:
         self.fill_s += t_close - self._fill_t0
         batch = self._batches_closed
         self._batches_closed = batch + 1
-        with obs.span("frontend.close", batch=batch, keys=len(self._buf_futs)):
+        with obs.span("frontend.close", batch=batch, keys=len(self._buf_futs), slow=self._buf_slow):
             tenants = np.asarray(self._buf_tenants, dtype=np.int64)
             lbas = np.asarray(self._buf_lbas, dtype=np.int64)
             fps = np.asarray(self._buf_fps, dtype=np.uint64)
@@ -228,6 +243,7 @@ class AsyncDedupFrontend:
             t0s = self._buf_t0
             self._buf_tenants, self._buf_lbas, self._buf_fps = [], [], []
             self._buf_futs, self._buf_t0 = [], []
+            self._buf_slow = 0
             loop = asyncio.get_running_loop()
             self._inflight_batches += 1
             job = loop.run_in_executor(self._engine_pool, self._execute_batch, tenants, lbas, fps,
@@ -249,55 +265,130 @@ class AsyncDedupFrontend:
 
     def _on_batch_done(self, job, futs, t0s, tenants, batch: int) -> None:
         now = time.perf_counter()
+        n = len(futs)
         self.batches_executed += 1
-        self.records_executed += len(futs)
+        self.records_executed += n
         self._inflight_batches -= 1
         self._cap_memo = None  # estimator/cache state moved: recompute caps
         err = job.exception()
-        flags = None if err is not None else job.result()
-        with obs.span("frontend.ack", batch=batch, keys=len(futs)):
-            for i, fut in enumerate(futs):
-                tenant = int(tenants[i])
-                self._inflight[tenant] -= 1
-                self._sem.release()
-                q = self._qos(tenant)
-                if err is not None:
+        with obs.span("frontend.ack", batch=batch, keys=n):
+            # per tenant: the batch's writes grouped by a stable sort
+            order = np.argsort(tenants, kind="stable")
+            grouped = tenants[order]
+            starts = np.flatnonzero(np.r_[True, grouped[1:] != grouped[:-1]])
+            groups = list(zip(grouped[starts].tolist(), starts.tolist(),
+                              np.r_[starts[1:], n].tolist()))
+            inflight = self._inflight
+            for t, a, b in groups:
+                inflight[t] -= b - a
+            # waiters take the freed slots first: they resume before the
+            # clients acknowledged below can submit again
+            self._release(n)
+            if err is not None:
+                for fut in futs:
                     if not fut.done():
                         fut.set_exception(err)
-                    continue
-                q.completed += 1
-                deduped = bool(flags[i])
-                q.deduped += int(deduped)
-                q.latencies.append(now - t0s[i])
-                if not fut.done():
-                    fut.set_result(deduped)
+            else:
+                flags = np.asarray(job.result(), dtype=bool)
+                lat = (now - np.asarray(t0s))[order]
+                deduped = np.add.reduceat(flags[order], starts, dtype=np.int64).tolist()
+                for (t, a, b), d in zip(groups, deduped):
+                    q = self.tenants[t]
+                    q.completed += b - a
+                    q.deduped += d
+                    q.latencies.append(lat[a:b])
+                for fut, flag in zip(futs, flags.tolist()):
+                    if not fut.done():  # the client may have cancelled it
+                        fut.set_result(flag)
         # wake admission-cap waiters so they re-check their budget
         self._drained.set()
         self._drained.clear()
         self.ack_s += time.perf_counter() - now
 
+    # -- backpressure ----------------------------------------------------------
+    def _release(self, n: int) -> None:
+        """Give back ``n`` slots and hand free slots to waiters in FIFO order."""
+        self._pending -= n
+        waiters = self._waiters
+        while waiters and self._pending < self.max_pending:
+            w = waiters.popleft()
+            if not w.done():
+                w.set_result(None)
+                self._pending += 1
+                self._granted += 1
+
+    async def _acquire_slow(self) -> None:
+        """Wait for a backpressure slot behind every earlier waiter."""
+        if self._pending < self.max_pending and not self._waiters and not self._granted:
+            self._pending += 1
+            return
+        w = asyncio.get_running_loop().create_future()
+        self._waiters.append(w)
+        try:
+            await w
+        except asyncio.CancelledError:
+            if w.done() and not w.cancelled():  # handed a slot, then cancelled
+                self._granted -= 1
+                self._release(1)
+            else:
+                try:
+                    self._waiters.remove(w)
+                except ValueError:  # _release already dropped it
+                    pass
+            raise
+        self._granted -= 1
+        if not self._granted:  # writers that queued behind handed-out slots may take free ones
+            self._release(0)
+
     # -- client surface --------------------------------------------------------
-    async def write(self, tenant: int, fp: int, lba: Optional[int] = None) -> bool:
-        """Submit one write for ``tenant``; resolves to the inline-dedup flag.
+    def write(self, tenant: int, fp: int, lba: Optional[int] = None) -> Awaitable[bool]:
+        """Submit one write for ``tenant``; returns an awaitable that resolves
+        to the inline-dedup flag once the engine applied the write.
 
         ``lba`` defaults to the tenant's next sequential logical block (the
-        common log-append shape); pass it explicitly for overwrite traffic."""
+        common log-append shape); pass it explicitly for overwrite traffic.
+        The write is buffered at once when the pipeline has room, no earlier
+        writer waits on backpressure and the tenant is under its admission
+        cap; otherwise the awaitable is the slow path's coroutine."""
         if self._closed:
             raise RuntimeError("frontend is closed")
-        q = self._qos(tenant)
+        q = self.tenants.get(tenant)
+        if q is None:
+            q = self._qos(tenant)
         q.submitted += 1
         t0 = time.perf_counter()
+        if (
+            self._pending < self.max_pending
+            and not self._waiters
+            and not self._granted
+            and (not self.admission_control
+                 or self._inflight.get(tenant, 0) < self._tenant_cap(tenant))
+        ):
+            self._pending += 1
+            return self._buffer(tenant, fp, lba, t0)
+        return self._write_slow(q, tenant, fp, lba, t0)
+
+    async def _write_slow(self, q: TenantQoS, tenant: int, fp: int, lba: Optional[int],
+                          t0: float) -> bool:
+        """Wait on the tenant's admission cap, then on backpressure, then
+        buffer the write."""
+        self.slow_writes += 1
         inflight = self._inflight
         if self.admission_control and inflight.get(tenant, 0) >= self._tenant_cap(tenant):
             q.throttled += 1
             while inflight.get(tenant, 0) >= self._tenant_cap(tenant):
                 await self._drained.wait()
-        await self._sem.acquire()  # global backpressure
-        inflight[tenant] = inflight.get(tenant, 0) + 1
+        await self._acquire_slow()
+        self._buf_slow += 1
+        return await self._buffer(tenant, fp, lba, t0)
+
+    def _buffer(self, tenant: int, fp: int, lba: Optional[int], t0: float) -> asyncio.Future:
+        """Append a write that holds a backpressure slot to the open batch."""
+        self._inflight[tenant] = self._inflight.get(tenant, 0) + 1
         if lba is None:
             lba = self._next_lba.get(tenant, 0)
             self._next_lba[tenant] = lba + 1
-        fut = asyncio.get_running_loop().create_future()
+        fut = asyncio.Future()  # on the running loop; half the cost of create_future()
         if not self._buf_futs:  # a batch opens: its fill span runs until _flush
             self._fill = obs.span("frontend.fill", batch=self._batches_closed)
             self._fill.__enter__()
@@ -308,7 +399,7 @@ class AsyncDedupFrontend:
         self._buf_futs.append(fut)
         self._buf_t0.append(t0)
         self._schedule_flush()
-        return await fut
+        return fut
 
     async def drain(self) -> None:
         """Flush the open batch and wait for every queued batch to complete."""
@@ -386,8 +477,12 @@ class AsyncDedupFrontend:
 
     def stats(self) -> dict:
         """Aggregate + per-tenant QoS view (latencies in milliseconds)."""
-        all_lat = [v for q in self.tenants.values() for v in q.latencies]
-        arr = np.asarray(all_lat) if all_lat else np.zeros(1)
+        per = {t: q.latency_samples() for t, q in sorted(self.tenants.items())}
+        all_lat = np.concatenate(list(per.values())) if per else np.zeros(0)
+
+        def ms(lat: np.ndarray, pct: float) -> float:
+            return round(float(np.percentile(lat, pct)) * 1e3, 3) if lat.size else 0.0
+
         return {
             "tenants": {
                 t: {
@@ -395,14 +490,15 @@ class AsyncDedupFrontend:
                     "completed": q.completed,
                     "deduped": q.deduped,
                     "throttled": q.throttled,
-                    "p50_ms": round(q.percentile_ms(50), 3),
-                    "p99_ms": round(q.percentile_ms(99), 3),
+                    "p50_ms": ms(per[t], 50),
+                    "p99_ms": ms(per[t], 99),
                 }
                 for t, q in sorted(self.tenants.items())
             },
             "completed": int(sum(q.completed for q in self.tenants.values())),
             "deduped": int(sum(q.deduped for q in self.tenants.values())),
             "throttled": int(sum(q.throttled for q in self.tenants.values())),
+            "slow_writes": self.slow_writes,
             "batches": self.batches_executed,
             "mean_batch": round(self.records_executed / self.batches_executed, 1)
             if self.batches_executed
@@ -410,6 +506,6 @@ class AsyncDedupFrontend:
             "fill_s": self.fill_s,
             "queue_wait_s": self.queue_wait_s,
             "ack_s": self.ack_s,
-            "p50_ms": round(float(np.percentile(arr, 50)) * 1e3, 3) if all_lat else 0.0,
-            "p99_ms": round(float(np.percentile(arr, 99)) * 1e3, 3) if all_lat else 0.0,
+            "p50_ms": ms(all_lat, 50),
+            "p99_ms": ms(all_lat, 99),
         }

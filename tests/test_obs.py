@@ -216,6 +216,46 @@ def test_frontend_counters_add_up(served):
     assert sum(fills) == pytest.approx(fe.fill_s, rel=0.05, abs=1e-3)
 
 
+@pytest.mark.parametrize("max_pending,waited", [(64, 0), (4, 60)])
+def test_close_span_counts_the_writes_that_waited(tmp_path, max_pending, waited):
+    """``frontend.close``'s ``slow`` stat is the closed batch's writes that
+    took the slow path: none while the front end has room, and every write
+    beyond the first ``max_pending`` when 64 arrive at once."""
+
+    class Standin:
+        def write_batch(self, streams, lbas, fps):
+            return np.zeros(len(fps), dtype=bool)
+
+    async def serve():
+        fe = AsyncDedupFrontend(Standin(), max_batch=8, max_delay=0.001, max_pending=max_pending)
+        try:
+            await asyncio.gather(*(fe.write(i % 4, i + 1) for i in range(64)))
+        finally:
+            await fe.close()
+        return fe
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fe = asyncio.run(serve())
+    finally:
+        jax.profiler.stop_trace()
+    closes = [st for _, spans in _threads(str(tmp_path)) for name, _, _, st in spans
+              if name == "frontend.close"]
+    assert len(closes) == fe.batches_executed
+    assert sum(st["keys"] for st in closes) == 64
+    assert sum(st["slow"] for st in closes) == fe.stats()["slow_writes"] == waited
+
+
+def test_served_slow_writes_are_the_admission_caps_waits(served):
+    """The served front end has room for every write, so only the contended
+    cache's admission caps send writes down the slow path."""
+    _, fe, threads = served
+    closes = [st for _, spans in threads for name, _, _, st in spans if name == "frontend.close"]
+    stats = fe.stats()
+    assert len(closes) == BATCHES
+    assert sum(st["slow"] for st in closes) == stats["slow_writes"] == stats["throttled"] > 0
+
+
 def test_launch_spans_carry_the_index_counters(served):
     cluster, _, threads = served
     puts = [st for _, spans in threads for name, _, _, st in spans if name == "fp_index.put"]
