@@ -1,6 +1,6 @@
 """Where JAX keeps its persistent compilation cache.
 
-Entry points (``chip_smoke.py``, the benchmarks) call ``enable_compile_cache``
+Entry points (``chip_smoke.py``, ``bench/run.py``) call ``enable_compile_cache``
 once at start; importing this module changes nothing.  The rule:
 
 * ``JAX_COMPILATION_CACHE_DIR`` set: JAX already reads it — use it and set

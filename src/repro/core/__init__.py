@@ -89,7 +89,7 @@ class Engine(Protocol):
     """One driver interface from trace ingest to reporting.
 
     ``HPDedup`` (and its ``make_idedup`` configuration), ``DIODE`` and
-    ``PurePostProcessing`` all implement it, so benchmarks, the data
+    ``PurePostProcessing`` all implement it, so the cluster, the data
     pipeline and the serving layer drive every engine the same way:
     columnar batches in, a ``HybridReport`` out.  Engines additionally
     expose ``replay_batched`` (the fast columnar path); ``replay`` stays

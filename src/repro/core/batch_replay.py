@@ -180,26 +180,27 @@ def engine_run_batch(engine, rb: ReplayBatch, out: Optional[np.ndarray] = None) 
     close pending duplicate runs at chunk boundaries (the scalar oracle only
     flushes once, at the end of the whole replay).
 
-    The built-in engines dispatch to their non-flushing columnar drivers.
-    Other ``Engine`` implementations fall back to their own protocol
-    surface — ``write_batch`` for write-only batches, ``replay`` over the
-    reconstructed records otherwise — so any protocol-conformant engine
-    works as a cluster shard (flush timing inside the fallback is then the
-    engine's own business).
+    A write-only batch (``rb.op is None``: the cluster's served
+    ``write_batch`` path) goes through the engine's own protocol entry
+    point, ``write_batch``, with its inline flags copied into ``out``.
+    Trace records go to the built-in engines' non-flushing columnar
+    drivers; other ``Engine`` implementations replay the reconstructed
+    records — so any protocol-conformant engine works as a cluster shard
+    (flush timing inside the fallback is then the engine's own business).
     """
     from .baselines import DIODE, PurePostProcessing
     from .hybrid import HPDedup
 
-    if isinstance(engine, HPDedup):
+    if rb.op is None:
+        flags = engine.write_batch(rb.stream, rb.lba, rb.fp)
+        if out is not None:
+            out[: len(rb)] = flags
+    elif isinstance(engine, HPDedup):
         hpdedup_run(engine, rb, out)
     elif isinstance(engine, DIODE):
         _diode_bulk(engine, rb, out, 0)
     elif isinstance(engine, PurePostProcessing):
         _postproc_bulk(engine, rb)
-    elif rb.op is None:
-        flags = engine.write_batch(rb.stream, rb.lba, rb.fp)
-        if out is not None:
-            out[: len(rb)] = flags
     else:
         recs = np.zeros(len(rb), dtype=TRACE_DTYPE)
         recs["stream"] = rb.stream

@@ -13,19 +13,18 @@ per shard stays small enough to serve heavy multi-tenant traffic.
 
 ``ShardedCluster`` implements the same ``Engine`` protocol as the engines
 it wraps (``write_batch`` / ``replay`` / ``finish``), so the data pipeline,
-the serving layer and every benchmark can swap a single engine for a
+the serving layer and the benchmark can swap a single engine for a
 cluster without code changes:
 
-* **Routing** — ``routing="fingerprint"`` (default) consistent-hashes the
-  fingerprint; ``routing="stream"`` pins whole streams to shards (FASTEN's
-  stream-affinity placement: better locality per shard, but cross-shard
-  duplicates stay unmerged — per-shard exactness only).
-* **Batched scatter** — ``replay_batched`` reuses the columnar
-  ``ReplayBatch`` machinery: shard ids for a whole chunk come from one
-  vectorized hash + ``searchsorted`` over the ring, the chunk scatters into
-  per-shard sub-batches in one pass (``ReplayBatch.scatter``), and each
-  sub-batch runs through the shard's PR-1 batched driver — the batched
-  throughput win carries over per shard.
+* **Routing** — one rule: writes consistent-hash their fingerprint, so the
+  shards partition the fingerprint space.
+* **One dispatch** — ``write_batch`` (the served path) and
+  ``ingest_batched`` (set-up, resumable ingest, ``replay_batched``) reach
+  the shards through one per-chunk dispatch: shard ids for a whole chunk
+  come from one vectorized hash + ``searchsorted`` over the ring, the chunk
+  scatters into per-shard sub-batches in one pass (``ReplayBatch.scatter``),
+  and each sub-batch runs through the shard's batched driver
+  (``engine_run_batch``) — inline on the coordinator or on its worker.
 * **Read routing** — under fingerprint partitioning the LBA mapping for a
   key lives wherever its *content* hashed, so the cluster keeps a routing
   directory ((stream, lba) -> shard, the routing tier's metadata) updated
@@ -68,6 +67,7 @@ allocate from that range again.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import queue
 import threading
@@ -310,10 +310,9 @@ class ParallelShardExecutor:
 def aggregate_reports(reports: Sequence[HybridReport]) -> HybridReport:
     """Sum per-shard reports into one cluster-level ``HybridReport``.
 
-    With fingerprint routing the shards partition the fingerprint space, so
-    summed ``unique_fingerprints`` / ``total_dup_writes`` equal the global
-    single-engine values; under stream routing they over-count content
-    duplicated across shards (per-shard exactness only).  Peak disk blocks
+    The shards partition the fingerprint space, so summed
+    ``unique_fingerprints`` / ``total_dup_writes`` equal the global
+    single-engine values.  Peak disk blocks
     is the sum of per-shard peaks — exact while shards only grow (no
     overwrites before the finish-time cleanup), an upper bound otherwise.
     """
@@ -456,12 +455,9 @@ class ShardedCluster:
         replication_factor: int = 1,
         **engine_kwargs,
     ):
-        if routing not in ("fingerprint", "stream"):
-            raise ValueError(f"routing must be 'fingerprint' or 'stream', got {routing!r}")
+        _check_routing(routing)
         if replication_factor < 1:
             raise ValueError(f"replication_factor must be >= 1, got {replication_factor}")
-        if replication_factor > 1 and routing != "fingerprint":
-            raise ValueError("replication requires fingerprint routing")
         if engine_factory is None:
             self._engine_kwargs: Optional[dict] = dict(engine_kwargs)
             engine_factory = lambda shard: HPDedup(seed=seed + shard, **engine_kwargs)
@@ -470,7 +466,6 @@ class ShardedCluster:
         else:
             self._engine_kwargs = None  # custom factory: not serializable
         self.num_shards = num_shards
-        self.routing = routing
         self._vnodes = vnodes
         self._seed = seed
         self._pba_stride = pba_stride
@@ -498,10 +493,11 @@ class ShardedCluster:
         # parallel-dispatch floor: chunks whose largest per-shard sub-batch
         # is smaller run inline on the coordinator instead of being
         # scattered to all workers (thread handoff + GIL thrash costs more
-        # than the work on tiny sub-batches; measured 0.41x on a 1-CPU host
-        # under fingerprint routing).  Plain attribute, not serialized.
+        # than the work on tiny sub-batches; measured 0.41x on a 1-CPU
+        # host).  Plain attribute, not serialized.
         self.min_parallel_batch = 2048
         # write_batch calls so far: the ``batch`` stat of its profiler spans
+        # (ingest_batched's shard spans carry batch -1)
         self.write_batches = 0
         # garbage collections as profiler spans too (one hook per process)
         obs.trace_gc()
@@ -663,6 +659,19 @@ class ShardedCluster:
                 self._executor = ParallelShardExecutor(self.num_shards, max_queued=max_queued)
             return self._executor
 
+    @contextlib.contextmanager
+    def _own_executor(self, parallel: bool):
+        """``parallel=True`` with no executor attached: attach one for the
+        call and stop it on the way out."""
+        own = parallel and self._executor is None and self.num_shards > 1
+        if own:
+            self.start_executor()
+        try:
+            yield
+        finally:
+            if own:
+                self.stop_executor()
+
     def stop_executor(self) -> None:
         """Drain outstanding work, then stop and detach the worker threads.
 
@@ -733,15 +742,57 @@ class ShardedCluster:
         else:
             self._workers_dirty = True
 
-    def _run_inline(self, parts, runner) -> None:
-        """Coalesced path: run a chunk's sub-batches on the coordinator.
-        Any still-queued worker item for these shards must finish first —
-        shard engines are single-touch (see ParallelShardExecutor)."""
-        if self._workers_dirty:
-            self._sync()
+    def _dispatch(self, chunk: ReplayBatch, batch: int, out: Optional[np.ndarray] = None) -> None:
+        """Route one chunk, scatter it into per-shard sub-batches, and run
+        each through ``engine_run_batch`` on its shard.
+
+        Sub-batches run on the shard workers (GC epoch pinned) when an
+        executor is attached, the cluster has more than one shard and the
+        largest sub-batch reaches ``min_parallel_batch``; otherwise inline
+        on the coordinator, after any still-queued worker item — shard
+        engines are single-touch (see ParallelShardExecutor).  Records
+        routed to a failed shard are logged for recovery but not executed.
+
+        With ``out``, each shard writes its inline flags into its own slice
+        of a buffer in scatter order, and the chunk's flags land in ``out``
+        in call order after the barrier (failed shards' read False).
+        Without it nothing waits: the caller barriers once at its end.
+        ``batch`` is the ``shard.write_batch`` spans' ``batch`` stat."""
+        with obs.span("cluster.route"):
+            sid = self._route_chunk(chunk)
+        with obs.span("cluster.scatter"):
+            parts, order = chunk.scatter(sid, self.num_shards)
+        flags = None if out is None else np.zeros(len(chunk), dtype=bool)
+        largest = max((len(sub) for sub in parts if sub is not None), default=0)
+        workers = (
+            self._executor is not None
+            and self.num_shards > 1
+            and largest >= self.min_parallel_batch
+        )
+        if not workers and self._workers_dirty:
+            with obs.span("cluster.wait"):
+                self._sync()
+        a = 0
         for s, sub in enumerate(parts):
-            if sub is not None and s not in self._failed:
-                runner(s, sub)
+            if sub is None:
+                continue
+            b = a + len(sub)
+            if s not in self._failed:
+                run = functools.partial(
+                    _run_shard, self.shards[s], s, sub, batch,
+                    None if flags is None else flags[a:b],
+                )
+                if workers:
+                    self._submit_pinned(s, run)
+                else:
+                    run()
+            a = b
+        if out is not None:
+            if workers:
+                with obs.span("cluster.wait"):
+                    self._sync()
+            with obs.span("cluster.gather"):
+                out[order] = flags
 
     def _make_shard_engine(self, shard: int):
         """Build shard ``shard``'s engine in the next unused PBA namespace
@@ -759,17 +810,9 @@ class ShardedCluster:
         return engine
 
     # -- routing -----------------------------------------------------------------
-    def shard_of_fp(self, fp: int) -> int:
-        return self.ring.shard_of(int(fp))
-
     def engine_for(self, fp: int):
-        """The shard engine owning ``fp``'s partition (fingerprint routing)."""
-        if self.routing != "fingerprint":
-            raise ValueError("engine_for(fp) requires fingerprint routing")
-        return self.shards[self.shard_of_fp(fp)]
-
-    def engine_for_stream(self, stream: int):
-        return self.shards[self.ring.shard_of(int(stream))]
+        """The shard engine owning ``fp``'s partition."""
+        return self.shards[self.ring.shard_of(int(fp))]
 
     @staticmethod
     def _packed_keys(streams: np.ndarray, lbas: np.ndarray) -> np.ndarray:
@@ -796,8 +839,6 @@ class ShardedCluster:
     def _route_chunk_ids(self, rb: ReplayBatch) -> np.ndarray:
         if self.num_shards == 1:
             return np.zeros(len(rb), dtype=np.int64)  # identity cluster
-        if self.routing == "stream":
-            return self.ring.shard_of_many(rb.stream.astype(np.uint64))
         num = self.num_shards
         sid = self.ring.shard_of_many(rb.fp)
         packed = self._packed_keys(rb.stream, rb.lba)
@@ -970,9 +1011,7 @@ class ShardedCluster:
         fingerprint?  One vectorized ring lookup routes the batch, then each
         owning shard's ``FingerprintIndex`` is probed with one batched
         launch — the scatter pre-pass's membership primitive, also the
-        serving layer's bulk existence check.  Under stream routing a
-        fingerprint may live on any shard, so every shard is probed and the
-        results OR-ed (still one launch per shard)."""
+        serving layer's bulk existence check."""
         keys = np.ascontiguousarray(fps, dtype=np.uint64)
         if keys.size == 0:
             return np.zeros(0, dtype=bool)
@@ -980,11 +1019,6 @@ class ShardedCluster:
         self._sync()  # probes read engine state the workers may be mutating
         if self.num_shards == 1:
             return _probe_seen(self.shards[0], keys)
-        if self.routing == "stream":
-            out = np.zeros(keys.size, dtype=bool)
-            for engine in self.shards:
-                out |= _probe_seen(engine, keys)
-            return out
         sid = self.ring.shard_of_many(keys)
         order = np.argsort(sid, kind="stable")
         counts = np.bincount(sid, minlength=self.num_shards)
@@ -1018,48 +1052,8 @@ class ShardedCluster:
         batch = self.write_batches
         self.write_batches = batch + 1
         with obs.span("cluster.write_batch", batch=batch, keys=len(rb)):
-            return self._write_batch(rb, batch)
-
-    def _write_batch(self, rb: ReplayBatch, batch: int) -> np.ndarray:
-        with obs.span("cluster.route"):
-            sid = self._route_chunk(rb)
-        out = np.zeros(len(rb), dtype=bool)
-        with obs.span("cluster.scatter"):
-            parts, order = rb.scatter(sid, self.num_shards)
-        ex = self._executor
-        largest = max((len(sub) for sub in parts if sub is not None), default=0)
-        if ex is None or self.num_shards == 1 or largest < self.min_parallel_batch:
-            if ex is not None and self._workers_dirty:
-                with obs.span("cluster.wait"):
-                    self._sync()
-            flags = []
-            for s, sub in enumerate(parts):
-                if sub is not None:
-                    if s in self._failed:
-                        flags.append(np.zeros(len(sub), dtype=bool))
-                    else:
-                        with obs.span("shard.write_batch", shard=s, batch=batch, keys=len(sub)):
-                            flags.append(self.shards[s].write_batch(sub.stream, sub.lba, sub.fp))
-        else:
-            results: List[Optional[np.ndarray]] = [None] * self.num_shards
-
-            def _run(s, sub):
-                with obs.span("shard.write_batch", shard=s, batch=batch, keys=len(sub)):
-                    results[s] = self.shards[s].write_batch(sub.stream, sub.lba, sub.fp)
-
-            for s, sub in enumerate(parts):
-                if sub is not None and s not in self._failed:
-                    self._submit_pinned(s, lambda s=s, sub=sub: _run(s, sub))
-            with obs.span("cluster.wait"):
-                self._sync()
-            flags = [
-                results[s] if results[s] is not None else np.zeros(len(sub), dtype=bool)
-                for s, sub in enumerate(parts)
-                if sub is not None
-            ]
-        with obs.span("cluster.gather"):
-            if flags:
-                out[order] = np.concatenate(flags)
+            out = np.zeros(len(rb), dtype=bool)
+            self._dispatch(rb, batch, out)
         return out
 
     @_locked
@@ -1104,44 +1098,20 @@ class ShardedCluster:
         run inline on the coordinator (same per-shard order, so bit-exact).
 
         ``on_chunk(i)`` fires after chunk ``i`` is dispatched (not yet
-        necessarily drained) — the hook the online-GC harness and benchmark
-        use to schedule ``run_gc(wait=False)`` against genuinely in-flight
-        traffic."""
+        necessarily drained) — the hook the online-GC tests use to schedule
+        ``run_gc(wait=False)`` against genuinely in-flight traffic."""
         with self._lock:
             self._check_poisoned()
-            own = parallel and self._executor is None and self.num_shards > 1
-            if own:
-                self.start_executor()
             rb = ReplayBatch.from_trace(trace)
-            try:
+            with self._own_executor(parallel):
                 for i, chunk in enumerate(rb.batches(batch_size * self.num_shards)):
-                    ex = self._executor  # on_chunk may fail/recover shards
-                    sid = self._route_chunk(chunk)
-                    parts, _ = chunk.scatter(sid, self.num_shards)
-                    largest = max((len(sub) for sub in parts if sub is not None), default=0)
-                    if ex is None or largest < self.min_parallel_batch:
-                        if ex is not None:
-                            self._run_inline(
-                                parts, lambda s, sub: engine_run_batch(self.shards[s], sub)
-                            )
-                        else:
-                            for s, sub in enumerate(parts):
-                                if sub is not None and s not in self._failed:
-                                    engine_run_batch(self.shards[s], sub)
-                    else:
-                        for s, sub in enumerate(parts):
-                            if sub is not None and s not in self._failed:
-                                engine = self.shards[s]
-                                self._submit_pinned(
-                                    s, lambda engine=engine, sub=sub: engine_run_batch(engine, sub)
-                                )
+                    # each chunk re-reads the executor: on_chunk may have
+                    # failed or recovered a shard (and restarted the workers)
+                    self._dispatch(chunk, -1)
                     if on_chunk is not None:
                         on_chunk(i)
                 if self._executor is not None:
                     self._sync()
-            finally:
-                if own:
-                    self.stop_executor()
         return self
 
     def replay_batched(
@@ -1150,97 +1120,28 @@ class ShardedCluster:
         batch_size: int = DEFAULT_BATCH_SIZE,
         parallel: bool = False,
     ) -> "ShardedCluster":
-        """Columnar batched replay: one vectorized route + scatter per chunk,
-        then each shard's PR-1 batched driver over its sub-batch.  Chunks are
+        """Columnar batched replay: ``ingest_batched`` plus the per-call
+        flush, logged so a failed shard's recovery replays it.  Chunks are
         ``batch_size * num_shards`` records so per-shard sub-batches stay
         near the tuned batch size.  ``parallel=True`` runs the shards on
         worker threads (pipelined coordinator, see ``ingest_batched``)."""
-        with self._lock:
-            own = parallel and self._executor is None and self.num_shards > 1
-            if own:
-                self.start_executor()
-            try:
-                self.ingest_batched(trace, batch_size, parallel=parallel)
-                # the per-call flush is engine-visible state: log it so a
-                # failed shard's recovery replays it at the same point
-                for s in range(self.num_shards):
-                    self._log_control(s, self._OP_FLUSH)
-                ex = self._executor
-                if ex is None:
-                    for s, engine in enumerate(self.shards):
-                        if s not in self._failed:
-                            engine_finish_replay(engine)
-                else:
-                    for s, engine in enumerate(self.shards):
-                        if s not in self._failed:
-                            self._submit_pinned(
-                                s, lambda engine=engine: engine_finish_replay(engine)
-                            )
-                    self._sync()
-            finally:
-                if own:
-                    self.stop_executor()
-        return self
-
-    def replay_batched_timed(self, trace: np.ndarray, batch_size: int = DEFAULT_BATCH_SIZE):
-        """Serial ``replay_batched`` with a per-phase wall-time breakdown.
-
-        Returns ``{"route": s, "scatter": s, "shard_times": [s, ...]}``.
-        This is the *diagnostic* view: it separates coordinator work
-        (route + scatter, paid once) from per-shard ingest time, with the
-        shards deliberately run serially so the per-phase attribution is
-        clean.  The *measured* parallel number comes from
-        ``replay_batched_parallel_timed`` — real worker threads, wall clock,
-        no modeling (the old ``route + scatter + max(shard_times)`` model is
-        kept only as a derived diagnostic in the scaling benchmark).
-        """
-        import time
-
-        with self._lock:
-            self._sync()
-            t_route = t_scatter = 0.0
-            shard_times = [0.0] * self.num_shards
-            rb = ReplayBatch.from_trace(trace)
-            for chunk in rb.batches(batch_size * self.num_shards):
-                t0 = time.perf_counter()
-                sid = self._route_chunk(chunk)
-                t1 = time.perf_counter()
-                parts, _ = chunk.scatter(sid, self.num_shards)
-                t2 = time.perf_counter()
-                t_route += t1 - t0
-                t_scatter += t2 - t1
-                for s, sub in enumerate(parts):
-                    if sub is not None and s not in self._failed:
-                        t3 = time.perf_counter()
-                        engine_run_batch(self.shards[s], sub)
-                        shard_times[s] += time.perf_counter() - t3
+        with self._lock, self._own_executor(parallel):
+            self.ingest_batched(trace, batch_size, parallel=parallel)
+            # the per-call flush is engine-visible state: log it so a
+            # failed shard's recovery replays it at the same point
+            for s in range(self.num_shards):
+                self._log_control(s, self._OP_FLUSH)
+            ex = self._executor
             for s, engine in enumerate(self.shards):
                 if s in self._failed:
                     continue
-                t3 = time.perf_counter()
-                engine_finish_replay(engine)
-                shard_times[s] += time.perf_counter() - t3
-            return {"route": t_route, "scatter": t_scatter, "shard_times": shard_times}
-
-    def replay_batched_parallel_timed(
-        self, trace: np.ndarray, batch_size: int = DEFAULT_BATCH_SIZE
-    ) -> dict:
-        """Measured (not modeled) parallel replay: wall-clock seconds for the
-        full pipelined run — coordinator routing/scatter overlapped with the
-        shard workers, ending at the barrier after the per-shard flush.
-
-        Returns ``{"wall": s, "started_executor": bool}``.  Uses the attached
-        executor when one is running (thread-start cost excluded); otherwise
-        spins one up for the call and includes its start/stop in the wall
-        time, which is the honest end-to-end number for a cold run."""
-        import time
-
-        t0 = time.perf_counter()
-        self.replay_batched(trace, batch_size=batch_size, parallel=True)
-        return {
-            "wall": time.perf_counter() - t0,
-            "started_executor": self._executor is None,
-        }
+                if ex is None:
+                    engine_finish_replay(engine)
+                else:
+                    self._submit_pinned(s, functools.partial(engine_finish_replay, engine))
+            if ex is not None:
+                self._sync()
+        return self
 
     def _invalidate_stale_keys(self) -> int:
         """Cross-shard overwrite invalidation (router-driven unref).
@@ -1254,7 +1155,7 @@ class ShardedCluster:
         oracle even on overwrite-heavy traces.  Callers must flush pending
         duplicate runs first so every mapping is final.
         """
-        if self.num_shards == 1 or self.routing != "fingerprint":
+        if self.num_shards == 1:
             return 0  # keys cannot straddle shards
         directory = self._directory
         dropped = 0
@@ -1412,13 +1313,12 @@ class ShardedCluster:
             if s in self._failed:
                 continue
             engine.store.check_consistency()
-            if self.routing == "fingerprint":
-                fps = list(engine.store.fp_table.keys())
-                if fps:
-                    owners = self.ring.shard_of_many(np.asarray(fps, dtype=np.uint64))
-                    assert bool((owners == s).all()), (
-                        f"shard {s} stores fingerprints owned by other shards"
-                    )
+            fps = list(engine.store.fp_table.keys())
+            if fps:
+                owners = self.ring.shard_of_many(np.asarray(fps, dtype=np.uint64))
+                assert bool((owners == s).all()), (
+                    f"shard {s} stores fingerprints owned by other shards"
+                )
 
     # -- deletes (cluster-level unmap with replica fan-out) ------------------------
     @_locked
@@ -1436,8 +1336,8 @@ class ShardedCluster:
         if owner is not None and owner < self.num_shards and owner not in self._failed:
             candidates = [owner]
         else:
-            # no (valid) directory row — stream routing, the pre-multi-shard
-            # era, or a failed owner: probe every live shard for the key
+            # no (valid) directory row — the pre-multi-shard era, or a
+            # failed owner: probe every live shard for the key
             candidates = [s for s in range(self.num_shards) if s not in self._failed]
         pba = None
         hit = None
@@ -1488,8 +1388,6 @@ class ShardedCluster:
         A lane poisoned by an injected worker fault is the expected entry
         path: the sticky error is absorbed here (the executor is restarted
         clean) and the shard transitions to cleanly-failed."""
-        if self.routing != "fingerprint":
-            raise RuntimeError("fail_shard requires fingerprint routing")
         if not 0 <= s < self.num_shards:
             raise IndexError(f"shard {s} out of range")
         if s in self._failed:
@@ -1549,7 +1447,7 @@ class ShardedCluster:
         # chunk-boundary-sensitive by design (triggers split batches, the
         # per-call flush is an event), so recovery re-batches exactly as
         # the live run executed — same sub-batch per chunk, same entry
-        # point per kind (write_batch / batched driver / scalar oracle),
+        # point per kind (batched driver / scalar oracle),
         # control events (flush, unmap) applied in sequence
         i, n = 0, len(log)
         while i < n:
@@ -1570,8 +1468,8 @@ class ShardedCluster:
             streams = np.asarray([e[1] for e in run], dtype=np.int32)
             lbas = np.asarray([e[2] for e in run], dtype=np.int64)
             fps = np.asarray([e[3] for e in run], dtype=np.uint64)
-            if op == -1:
-                engine.write_batch(streams, lbas, fps)
+            if op == -1:  # a write_batch chunk: write columns only
+                engine_run_batch(engine, ReplayBatch(streams, lbas, fps))
             elif run[0][7]:
                 sub = np.zeros(len(run), dtype=TRACE_DTYPE)
                 sub["stream"], sub["lba"], sub["fp"] = streams, lbas, fps
@@ -1648,11 +1546,6 @@ class ShardedCluster:
         """
         if new_num_shards < 1:
             raise ValueError(f"new_num_shards must be >= 1, got {new_num_shards}")
-        if self.routing != "fingerprint":
-            raise NotImplementedError(
-                "resize() requires fingerprint routing; stream-affinity "
-                "clusters would need whole-stream migration"
-            )
         self._check_not_failed("resize")
         self._check_poisoned()
         if engine_factory is not None:
@@ -1814,7 +1707,7 @@ class ShardedCluster:
         return {
             "config": {
                 "num_shards": self.num_shards,
-                "routing": self.routing,
+                "routing": "fingerprint",
                 "vnodes": self._vnodes,
                 "seed": self._seed,
                 "pba_stride": self._pba_stride,
@@ -1849,6 +1742,7 @@ class ShardedCluster:
                 self.stop_executor()
                 self.start_executor()
         config = tree["config"]
+        _check_routing(config["routing"])
         if config["num_shards"] != self.num_shards:
             raise ValueError(
                 f"snapshot has {config['num_shards']} shards but this cluster "
@@ -1859,15 +1753,14 @@ class ShardedCluster:
                 f"snapshot is corrupt: config says {self.num_shards} shards "
                 f"but carries {len(tree['shards'])} shard trees"
             )
-        if (
-            config["routing"],
-            config["vnodes"],
-            config["seed"],
-            config["pba_stride"],
-        ) != (self.routing, self._vnodes, self._seed, self._pba_stride):
+        if (config["vnodes"], config["seed"], config["pba_stride"]) != (
+            self._vnodes,
+            self._seed,
+            self._pba_stride,
+        ):
             raise ValueError(
-                "snapshot ring/namespace parameters (routing, vnodes, seed, "
-                "pba_stride) differ from this cluster's"
+                "snapshot ring/namespace parameters (vnodes, seed, pba_stride) "
+                "differ from this cluster's"
             )
         # validate every shard tree BEFORE any shard mutates (same rule as
         # resize's pre-checks): a kind/config mismatch on shard k would
@@ -1889,6 +1782,7 @@ class ShardedCluster:
         from .snapshot import report_from_tree, restore_engine
 
         config = tree["config"]
+        _check_routing(config["routing"])
         if len(tree["shards"]) != config["num_shards"]:
             raise ValueError(
                 f"snapshot is corrupt: config says {config['num_shards']} "
@@ -1898,7 +1792,6 @@ class ShardedCluster:
         # baked in), so bypass the ctor's shard construction entirely
         cluster = cls.__new__(cls)
         cluster.num_shards = config["num_shards"]
-        cluster.routing = config["routing"]
         cluster._vnodes = config["vnodes"]
         cluster._seed = config["seed"]
         cluster._pba_stride = config["pba_stride"]
@@ -1931,6 +1824,20 @@ class ShardedCluster:
         cluster._poisoned = {}
         cluster._load_replication(tree.get("replication"))
         return cluster
+
+
+def _check_routing(routing: str) -> None:
+    """Fingerprint routing is the one rule; the keyword (and the snapshot
+    config's ``routing`` key) accept only that value."""
+    if routing != "fingerprint":
+        raise ValueError(f"routing must be 'fingerprint', got {routing!r}")
+
+
+def _run_shard(engine, shard: int, sub: ReplayBatch, batch: int, out) -> None:
+    """One shard's sub-batch through its batched driver, as one
+    ``shard.write_batch`` span on the thread that runs it."""
+    with obs.span("shard.write_batch", shard=shard, batch=batch, keys=len(sub)):
+        engine_run_batch(engine, sub, out)
 
 
 def _seen_set_of(engine):

@@ -45,7 +45,3 @@ def host_fingerprint(block: Union[bytes, np.ndarray]) -> int:
         block = np.ascontiguousarray(block).tobytes()
     digest = hashlib.blake2b(block, digest_size=8).digest()
     return int.from_bytes(digest, "little") or 1  # avoid reserved 0
-
-
-def empty_trace(n: int) -> np.ndarray:
-    return np.zeros(n, dtype=TRACE_DTYPE)

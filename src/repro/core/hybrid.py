@@ -4,13 +4,13 @@ Fuses the inline phase (fingerprint cache + LDSS prioritization + spatial
 thresholds) with the post-processing phase (exact background dedup) over one
 BlockStore, and keeps the fingerprint cache coherent across post-processing
 merges.  This is the object the data pipeline and the serving KV-dedup layer
-embed; trace replay drives it directly for the paper-validation benchmarks.
+embed; trace replay drives it directly for the paper-claim tests.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, Iterable, Optional
+from typing import Dict, Optional
 
 import numpy as np
 
@@ -35,11 +35,6 @@ class HybridReport:
     def inline_dedup_ratio(self) -> float:
         """Share of duplicate writes identified by inline caching (Fig. 6)."""
         return self.inline.inline_dups / self.total_dup_writes if self.total_dup_writes else 0.0
-
-    @property
-    def capacity_requirement(self) -> int:
-        """Max disk blocks ever resident — the paper's Fig. 7 metric."""
-        return self.peak_disk_blocks
 
     @property
     def avg_hits_of_cached_fingerprints(self) -> float:
@@ -245,8 +240,3 @@ class HPDedup:
             total_writes=self._total_writes,
             total_dup_writes=self._dup_writes,
         )
-
-
-def replay_trace(trace: Iterable, engine: HPDedup) -> HybridReport:
-    engine.replay(np.asarray(trace, dtype=TRACE_DTYPE))
-    return engine.finish()

@@ -34,11 +34,6 @@ class InlineMetrics:
     per_stream_dups: Dict[int, int] = field(default_factory=dict)
     per_stream_writes: Dict[int, int] = field(default_factory=dict)
 
-    def inline_ratio(self, total_dup_writes: int) -> float:
-        """Paper's 'inline deduplication ratio': share of duplicate writes
-        identified inline."""
-        return self.inline_dups / total_dup_writes if total_dup_writes else 0.0
-
     def snapshot(self) -> dict:
         return {
             "writes": self.writes,

@@ -8,8 +8,8 @@ Two implementations:
 
 * ``Reservoir`` — the classic online host-side sampler used by the inline
   engine (one per stream; O(1) per element, O(k) memory).
-* ``reservoir_indices`` — a vectorized offline sampler used by benchmarks and
-  the JAX estimation path: given interval length ``n`` and reservoir size
+* ``reservoir_indices`` — a vectorized offline sampler for the JAX
+  estimation path: given interval length ``n`` and reservoir size
   ``k``, returns the sampled positions with the exact Algorithm-R
   distribution (every element equally likely to be retained).
 """

@@ -561,10 +561,6 @@ class BlockStore:
         """Is any live block's content fingerprinted ``fp``?"""
         return fp in self.fp_index
 
-    def contains_fps(self, fps):
-        """Batched fingerprint-table membership — one index launch."""
-        return self.fp_index.contains_many(fps)
-
     # -- post-processing support ---------------------------------------------------
     def duplicate_fingerprints(self) -> List[int]:
         """Fingerprints stored at more than one PBA (inline misses).

@@ -280,7 +280,7 @@ def unseen_estimate_jax_from_counts(
 
 
 def unseen_estimate_jax(f_batch: np.ndarray, n_batch: np.ndarray) -> np.ndarray:
-    """FFH-input convenience wrapper (used by tests/benchmarks)."""
+    """FFH-input convenience wrapper over ``unseen_estimate_jax_from_counts``."""
     f_batch = np.asarray(f_batch, dtype=np.int64)
     counts_list = [np.repeat(np.arange(1, f.size + 1), f) for f in f_batch]
     return unseen_estimate_jax_from_counts(counts_list, n_batch)

@@ -16,7 +16,7 @@ Per cell this script:
   4. records ``memory_analysis()`` (bytes/device), ``cost_analysis()``
      (FLOPs + bytes accessed, per device), and the collective schedule
      parsed from the compiled HLO (wire bytes per device per step),
-  5. appends a JSON record consumed by benchmarks/roofline.py.
+  5. appends a JSON record (one per cell) to the output file.
 
 Usage:
   python -m repro.launch.dryrun --arch tinyllama-1.1b --shape train_4k

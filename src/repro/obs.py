@@ -35,7 +35,9 @@ SPANS = {
     "frontend.close": "front end",  # _flush: list -> arrays, hand-off (keys, slow)
     "frontend.execute": "front end",  # engine thread, around write_batch (queue_us)
     "frontend.ack": "front end",  # _on_batch_done: per-tenant accounting, release, futures (keys)
-    # core/cluster.py; batch = the cluster's write_batch call number
+    # core/cluster.py; batch = the cluster's write_batch call number.  The
+    # route..shard spans come from _dispatch, which ingest_batched shares
+    # (its shard spans carry batch -1)
     "cluster.write_batch": "cluster and engines",  # coordinator (keys)
     "cluster.route": "cluster and engines",  # _route_chunk
     "cluster.scatter": "cluster and engines",  # ReplayBatch.scatter: per-shard sub-batches

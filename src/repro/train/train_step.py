@@ -1,4 +1,4 @@
-"""Train/serve step builders shared by the trainer, dry-run and benchmarks."""
+"""Train/serve step builders shared by the trainer and the dry-run."""
 
 from __future__ import annotations
 

@@ -46,3 +46,19 @@ else:
         "ci", max_examples=50, deadline=None, derandomize=True, print_blob=True
     )
     settings.load_profile("ci" if os.environ.get("CI") else "dev")
+
+
+def feed_cluster(cluster, trace, entry, batch_size):
+    """Drive ``trace`` through one of a ``ShardedCluster``'s two entry paths,
+    in chunks of ``batch_size`` per shard: ``ingest_batched`` takes the whole
+    trace, reads included; ``write_batch`` takes its writes, chunk by chunk."""
+    from repro.core.fingerprint import OP_WRITE
+
+    if entry == "ingest_batched":
+        cluster.ingest_batched(trace, batch_size=batch_size)
+        return
+    writes = trace[trace["op"] == OP_WRITE]
+    step = batch_size * cluster.num_shards
+    for lo in range(0, len(writes), step):
+        w = writes[lo : lo + step]
+        cluster.write_batch(w["stream"], w["lba"], w["fp"])

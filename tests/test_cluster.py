@@ -188,32 +188,6 @@ def test_write_batch_flags_match_single_engine():
     assert single.finish().total_dup_writes == cluster.finish().total_dup_writes
 
 
-def test_stream_affinity_routing_per_shard_exactness():
-    """Stream routing pins whole streams to shards: per-shard reports stay
-    exact and streams never straddle shards, but cross-shard content
-    duplicates may stay unmerged (documented tradeoff)."""
-    trace, _ = generate_workload("B", total_requests=6_000, seed=3)
-    oracle = HPDedup(cache_entries=512)
-    oracle.replay(trace)
-    oracle_rep = oracle.finish()
-    cluster = ShardedCluster(num_shards=4, cache_entries=512, routing="stream")
-    cluster.replay_batched(trace, batch_size=512)
-    rep = cluster.finish()
-    cluster.check_consistency()
-    assert rep.total_writes == oracle_rep.total_writes
-    # per-shard exactness: one block per live fingerprint on every shard
-    for shard_rep in cluster.shard_reports:
-        assert shard_rep.final_disk_blocks == shard_rep.unique_fingerprints
-    # stream partition: no stream's writes land on two shards
-    seen_streams = set()
-    for shard_rep in cluster.shard_reports:
-        streams = set(shard_rep.inline.per_stream_writes)
-        assert not (streams & seen_streams)
-        seen_streams |= streams
-    # cross-shard dups may remain: aggregate uniques can only over-count
-    assert rep.unique_fingerprints >= oracle_rep.unique_fingerprints
-
-
 def test_reads_route_to_the_writing_shard():
     """The routing directory sends a read to the shard holding its key, so
     cluster reads resolve like single-engine reads."""

@@ -276,16 +276,18 @@ def test_window_is_positive_sane():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("routing", ["fingerprint", "stream"])
+@pytest.mark.parametrize("ring", ["fresh", "resized"])
 @pytest.mark.parametrize("num_shards", [1, 4])
-def test_cluster_probe_fps_matches_oracle(routing, num_shards):
+def test_cluster_probe_fps_matches_oracle(ring, num_shards):
+    """``probe_fps`` routes each key to its owner on the current ring: on the
+    ring the cluster was built with, and after a resize moved keys."""
     from repro.core import ShardedCluster, generate_workload
 
     trace, _ = generate_workload("B", total_requests=4_000, seed=11)
-    cluster = ShardedCluster(
-        num_shards=num_shards, routing=routing, cache_entries=256
-    )
+    cluster = ShardedCluster(num_shards=num_shards, cache_entries=256)
     cluster.replay_batched(trace)
+    if ring == "resized":
+        assert cluster.resize(num_shards + 1)["moved_fps"] > 0
     written = {int(r["fp"]) for r in trace if r["op"] == 0}
 
     rng = np.random.default_rng(5)

@@ -26,6 +26,7 @@ from repro.core import (
     restore_engine,
     snapshot_engine,
 )
+from conftest import feed_cluster
 
 SHARD_COUNTS = [1, 2, 4, 8]
 
@@ -316,29 +317,29 @@ def test_gc_resize_with_orphan_reclaim():
 
 
 # ---------------------------------------------------------------------------
-# Satellite: sub-batch coalescing keeps both routings bit-exact.
+# Satellite: sub-batch coalescing keeps both entry paths bit-exact.
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("routing", ["fingerprint", "stream"])
-def test_coalescing_floor_is_bit_exact(routing):
+@pytest.mark.parametrize("entry", ["ingest_batched", "write_batch"])
+def test_coalescing_floor_is_bit_exact(entry):
     """Tiny sub-batches coalesced onto the coordinator (floor = huge) and
-    fully scattered to workers (floor = 0) produce identical reports; the
-    serial path is the oracle."""
+    fully scattered to workers (floor = 0) produce identical reports on
+    either entry path; the serial path is the oracle."""
     trace = _overwrite_trace(total=2_000, seed=11)
-    serial = _cluster(4, routing=routing)
-    serial.ingest_batched(trace, batch_size=128)
+    serial = _cluster(4)
+    feed_cluster(serial, trace, entry, 128)
     rep0 = serial.finish()
     for floor in (0, 1 << 30):
-        c = _cluster(4, routing=routing)
+        c = _cluster(4)
         c.min_parallel_batch = floor
         c.start_executor()
         try:
-            c.ingest_batched(trace, batch_size=128, parallel=True)
+            feed_cluster(c, trace, entry, 128)
             rep = c.finish()
         finally:
             c.stop_executor()
-        assert rep == rep0, f"floor={floor} diverged under {routing} routing"
+        assert rep == rep0, f"floor={floor} diverged through {entry}"
 
 
 def test_coalesced_write_batch_flags_match_workers():

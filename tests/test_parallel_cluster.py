@@ -26,6 +26,8 @@ from repro.core import (
     run_replay,
     snapshot_engine,
 )
+from repro.core.fingerprint import OP_WRITE
+from conftest import feed_cluster
 
 SHARD_COUNTS = [1, 2, 4, 8]
 
@@ -46,8 +48,8 @@ def _overwrite_trace(total=4_000, seed=13):
     return both
 
 
-def _cluster(num_shards, routing="fingerprint"):
-    return ShardedCluster(num_shards=num_shards, cache_entries=512, routing=routing)
+def _cluster(num_shards):
+    return ShardedCluster(num_shards=num_shards, cache_entries=512)
 
 
 @pytest.mark.parametrize("num_shards", SHARD_COUNTS)
@@ -59,12 +61,45 @@ def test_parallel_replay_bit_exact_vs_serial(num_shards, make_trace):
     assert parallel.finish() == serial.finish()
 
 
-@pytest.mark.parametrize("routing", ["fingerprint", "stream"])
-def test_parallel_matches_serial_under_both_routings(routing):
+@pytest.mark.parametrize("entry", ["ingest_batched", "write_batch"])
+def test_parallel_matches_serial_on_both_entry_paths(entry):
+    """Both entry paths reach the shards through the one dispatch: on the
+    workers (floor 0) they leave the serial path's report."""
     trace = _trace(5_000, seed=21)
-    serial = _cluster(4, routing).replay_batched(trace, batch_size=512)
-    parallel = _cluster(4, routing).replay_batched(trace, batch_size=512, parallel=True)
-    assert parallel.finish() == serial.finish()
+    serial = _cluster(4)
+    feed_cluster(serial, trace, entry, 512)
+    parallel = _cluster(4)
+    parallel.min_parallel_batch = 0
+    parallel.start_executor()
+    try:
+        feed_cluster(parallel, trace, entry, 512)
+        rep = parallel.finish()
+    finally:
+        parallel.stop_executor()
+    assert rep == serial.finish()
+
+
+@pytest.mark.parametrize("mode", ["serial", "parallel"])
+def test_write_batch_and_ingest_share_state(mode):
+    """A write-only trace fed through chunked ``write_batch`` and through
+    ``ingest_batched`` with the same chunking leaves the same per-shard
+    engine state and the same report: one dispatch, one shard driver."""
+    trace = _trace(5_000, seed=23)
+    trace = trace[trace["op"] == OP_WRITE]
+    trees, reports = [], []
+    for entry in ("write_batch", "ingest_batched"):
+        c = _cluster(4)
+        if mode == "parallel":
+            c.min_parallel_batch = 0
+            c.start_executor()
+        try:
+            feed_cluster(c, trace, entry, 256)
+            trees.append(json.dumps(c.snapshot()["shards"]))
+            reports.append(c.finish())
+        finally:
+            c.stop_executor()
+    assert trees[0] == trees[1]
+    assert reports[0] == reports[1]
 
 
 def test_parallel_write_batch_flags_match_serial():

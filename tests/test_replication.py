@@ -23,6 +23,7 @@ import numpy as np
 import pytest
 
 from repro.core import ShardedCluster, ShardWorkerError, generate_workload
+from repro.core.fingerprint import OP_WRITE
 
 REPLICATION = [2, 3]
 SHARD_COUNTS = [2, 4, 8]
@@ -90,6 +91,41 @@ def test_kill_mid_parallel_replay_recover_bit_exact(num_shards, factor):
     assert got == expected
     assert _live_digest(c) == _live_digest(oracle)
     assert c.replica_blocks == oracle.replica_blocks
+
+
+@pytest.mark.parametrize("parallel", [False, True], ids=["serial", "parallel"])
+def test_served_writes_while_a_shard_is_down_recover_bit_exact(parallel):
+    """``write_batch`` with shard 1 of 4 failed: records routed to it read
+    False and are logged, the other shards' flags land in call order as a
+    healthy cluster's do, and recovery rolls the logged served writes
+    forward to the oracle's report and live blocks."""
+    trace = _trace(8_000, seed=17)
+    writes = trace[trace["op"] == OP_WRITE]
+    victim, step = 1, 1_024
+    chunks = [writes[lo : lo + step] for lo in range(0, len(writes), step)]
+    half = len(chunks) // 2
+
+    def serve(c, part):
+        return np.concatenate([c.write_batch(w["stream"], w["lba"], w["fp"]) for w in part])
+
+    oracle = _cluster(4, 2)
+    c = _cluster(4, 2)
+    if parallel:
+        c.min_parallel_batch = 0
+        c.start_executor()
+    try:
+        assert np.array_equal(serve(c, chunks[:half]), serve(oracle, chunks[:half]))
+        c.fail_shard(victim)
+        got, want = serve(c, chunks[half:]), serve(oracle, chunks[half:])
+        rest = np.concatenate(chunks[half:])
+        down = c.ring.shard_of_many(rest["fp"]) == victim
+        assert down.any() and not got[down].any()
+        assert want[~down].any() and np.array_equal(got[~down], want[~down])
+        assert c.recover_shard(victim)["replayed"] > 0
+        assert c.finish() == oracle.finish()
+    finally:
+        c.stop_executor()
+    assert _live_digest(c) == _live_digest(oracle)
 
 
 def test_r1_oracle_equals_unreplicated_cluster():
@@ -374,10 +410,15 @@ def test_replication_clamp_warns():
 
 
 def test_replication_requires_fingerprint_routing():
-    with pytest.raises(ValueError, match="fingerprint"):
-        ShardedCluster(
-            num_shards=2, cache_entries=512, routing="stream", replication_factor=2
-        )
+    """Fingerprint routing is the only rule: construction rejects any other
+    value, replicated or not."""
+    for factor in (1, 2):
+        for routing in ("stream", "lba", ""):
+            with pytest.raises(ValueError, match="fingerprint"):
+                ShardedCluster(
+                    num_shards=2, cache_entries=512, routing=routing,
+                    replication_factor=factor,
+                )
 
 
 def test_grow_unclamps_replication():

@@ -197,6 +197,22 @@ def test_cluster_load_snapshot_shape_guard():
         load_engine_state(narrow, tree)
 
 
+def test_cluster_snapshot_rejects_any_routing_but_fingerprint():
+    """Fingerprint routing is the one rule: the snapshot keeps its
+    ``routing`` key, and both the in-place load and the from-scratch
+    restore refuse a tree that names any other value."""
+    cluster = ShardedCluster(num_shards=2, cache_entries=16)
+    tree = json.loads(json.dumps(snapshot_engine(cluster)))
+    assert tree["state"]["config"]["routing"] == "fingerprint"
+    tree["state"]["config"]["routing"] = "stream"
+    before = json.dumps(snapshot_engine(cluster))
+    with pytest.raises(ValueError, match="routing"):
+        load_engine_state(cluster, tree)
+    assert json.dumps(snapshot_engine(cluster)) == before  # untouched
+    with pytest.raises(ValueError, match="routing"):
+        restore_engine(tree)
+
+
 def test_cluster_load_snapshot_rejects_without_mutating(trace):
     """A per-shard config mismatch must reject BEFORE any shard loads: a
     mid-loop failure would leave earlier shards on snapshot state and later
