@@ -27,6 +27,9 @@ from .fp_index import FingerprintIndex
 from .segment_tree import FenwickSegments
 from .statetree import from_pairs, pairs
 
+# victim uniforms drawn from the eviction generator per ``random(n)`` call
+_DRAW_BLOCK = 2048
+
 # ---------------------------------------------------------------------------
 # Replacement policies (per-stream building blocks).
 # ---------------------------------------------------------------------------
@@ -427,8 +430,12 @@ class PrioritizedCache:
         self.owner: Dict[int, int] = {}  # fp -> stream whose sub-cache holds it
         # resident-fingerprint index: membership mirror of ``owner``'s key
         # set, probed in one batched launch by the replay pre-pass (the
-        # owner dict stays authoritative for holder lookups)
-        self.index = FingerprintIndex()
+        # owner dict stays authoritative for holder lookups).  Admissions
+        # and evictions queue in ``_adds``/``_drops`` and reach the index in
+        # one ``add_discard`` when the ``index`` property is next read.
+        self._index = FingerprintIndex()
+        self._adds: list = []
+        self._drops: list = []
         self.ldss: Dict[int, float] = {}
         self._best_ldss = 0.0  # memoized max; recomputed on set_ldss only
         # per-stream admission verdicts; pure function of ``ldss``, so valid
@@ -437,6 +444,55 @@ class PrioritizedCache:
         self.segments = FenwickSegments()
         self.total = 0
         self.inserted = 0
+        self.evicted = 0  # a counter, not state: never serialized
+
+    # -- eviction RNG ---------------------------------------------------------
+    # Victim uniforms come from ``_rng`` in blocks of ``_DRAW_BLOCK`` (one
+    # ``random(n)`` instead of a slow scalar ``uniform`` per eviction); a
+    # victim is ``tot * u``, the double ``uniform(0.0, tot)`` gives on the
+    # same generator state.  ``_block_state`` is the state the block was
+    # drawn from, and ``_ui`` how many of it were used, so reading ``rng``
+    # rewinds the generator to exactly the draws consumed.
+    @property
+    def rng(self) -> np.random.Generator:
+        """The eviction generator, in the state the draws so far left it."""
+        start = self._block_state
+        if start is not None:
+            bg = self._rng.bit_generator
+            bg.state = start
+            if self._ui:
+                bg.advance(self._ui)
+                # advance() clears the 32-bit buffer, which doubles never touch
+                st = bg.state
+                st["has_uint32"], st["uinteger"] = start["has_uint32"], start["uinteger"]
+                bg.state = st
+        self.rng = self._rng  # drop the block: the generator is exact again
+        return self._rng
+
+    @rng.setter
+    def rng(self, rng: np.random.Generator) -> None:
+        self._rng = rng
+        self._block_state = None
+        self._u: list = []
+        self._ui = 0
+
+    def _draw_block(self) -> list:
+        """A fresh block of victim uniforms (the last one is used up)."""
+        self._block_state = self._rng.bit_generator.state
+        self._u = self._rng.random(_DRAW_BLOCK).tolist()
+        self._ui = 0
+        return self._u
+
+    @property
+    def index(self) -> FingerprintIndex:
+        """The resident-fingerprint index, with the admissions and evictions
+        queued since it was last read applied (so reading it mutates it:
+        like every other method, not while another thread admits)."""
+        if self._adds or self._drops:
+            self._index.add_discard(self._adds, self._drops)
+            self._adds.clear()
+            self._drops.clear()
+        return self._index
 
     # -- LDSS plumbing -------------------------------------------------------
     def set_ldss(self, ldss: Dict[int, float]) -> None:
@@ -496,7 +552,8 @@ class PrioritizedCache:
         return self.index.contains_many_async(fps)
 
     def admit(self, stream: int, fp: int, pba: int) -> None:
-        holder = self.owner.get(fp)
+        owner = self.owner
+        holder = owner.get(fp)
         if holder is not None:  # already cached (possibly by another stream)
             self.streams[holder].insert(fp, pba)
             return
@@ -505,22 +562,55 @@ class PrioritizedCache:
             adm = self._adm_memo[stream] = self._admitted(stream)
         if not adm:
             return
-        sub = self._sub(stream)
-        while self.total >= self.capacity:
-            if not self._evict():
-                break
-        sub.insert(fp, pba)
-        self.owner[fp] = stream
-        self.index.add(fp)
+        sub = self.streams.get(stream)
+        if sub is None:
+            sub = self._sub(stream)
+        if self.total >= self.capacity:
+            # the evicting admission: an LRU victim's entry is popped here
+            # directly; other policies go through ``_evict_from``
+            streams, drops = self.streams, self._drops
+            seg = self.segments
+            while self.total >= self.capacity:
+                tot = seg.total
+                victim = None
+                if tot > 0.0:
+                    u, i = self._u, self._ui
+                    if i == len(u):
+                        u, i = self._draw_block(), 0
+                    self._ui = i + 1
+                    victim = seg.locate(tot * u[i])
+                    vsub = streams[victim]
+                    if vsub.__class__ is LRUCache and vsub._d:
+                        vd = vsub._d
+                        vfp = vd.popitem(last=False)[0]
+                        owner.pop(vfp, None)
+                        drops.append(vfp)
+                        self.total -= 1
+                        self.evicted += 1
+                        if not vd:
+                            seg.set_weight(victim, 0.0)
+                        continue
+                if not self._evict_from(victim):
+                    break
+        if sub.__class__ is LRUCache:
+            d = sub._d
+            d[fp] = pba  # not resident: a fresh key lands at the MRU end
+            grew = len(d) == 1
+        else:
+            sub.insert(fp, pba)
+            grew = len(sub) == 1
+        owner[fp] = stream
+        self._adds.append(fp)
         self.total += 1
         self.inserted += 1
-        if len(sub) == 1:
+        if grew:
             # 0 -> 1: the stream just became evictable.  Otherwise its weight
             # (1/LDSS, length-independent) is unchanged — skip the Fenwick walk.
             self.segments.set_weight(stream, self._evict_priority(stream))
 
-    def _evict(self) -> bool:
-        victim_stream = self.segments.draw(self.rng)
+    def _evict_from(self, victim_stream: Optional[int]) -> bool:
+        """Evict one entry from the drawn victim stream (``None``: no
+        stream has weight)."""
         if victim_stream is None:
             # no weights (e.g. all LDSS unset): evict from the largest stream
             candidates = [(len(c), s) for s, c in self.streams.items() if len(c)]
@@ -533,8 +623,9 @@ class PrioritizedCache:
             self.segments.set_weight(victim_stream, 0.0)
             return self._evict_fallback()
         self.owner.pop(out[0], None)
-        self.index.discard(out[0])
+        self._drops.append(out[0])
         self.total -= 1
+        self.evicted += 1
         if len(sub) == 0:
             self.segments.set_weight(victim_stream, 0.0)
         return True
@@ -544,8 +635,9 @@ class PrioritizedCache:
             out = sub.evict_one()
             if out is not None:
                 self.owner.pop(out[0], None)
-                self.index.discard(out[0])
+                self._drops.append(out[0])
                 self.total -= 1
+                self.evicted += 1
                 if len(sub) == 0:
                     self.segments.set_weight(s, 0.0)
                 return True
@@ -575,12 +667,15 @@ class PrioritizedCache:
         }
 
     def load_snapshot(self, tree: dict) -> None:
-        self.rng = np.random.default_rng(0)
-        self.rng.bit_generator.state = tree["rng"]
+        rng = np.random.default_rng(0)
+        rng.bit_generator.state = tree["rng"]
+        self.rng = rng
         self.streams = {int(s): policy_from_snapshot(sub) for s, sub in tree["streams"]}
         self.owner = from_pairs(tree["owner"], value=int)
         # the index is derived, never serialized: rebuild from the owner map
-        self.index = FingerprintIndex(self.owner)
+        self._index = FingerprintIndex(self.owner)
+        self._adds.clear()
+        self._drops.clear()
         self.ldss = from_pairs(tree["ldss"], value=float)
         self._best_ldss = float(tree["best_ldss"])
         self._adm_memo = {}
@@ -590,8 +685,8 @@ class PrioritizedCache:
 
     def evict_fp(self, fp: int) -> Optional[int]:
         """Drop ``fp``; returns its PBA (resharding pulls moved entries out).
-        Mirrors ``_evict``'s bookkeeping but targets one fingerprint and
-        consumes no RNG."""
+        Mirrors an eviction's bookkeeping but targets one fingerprint,
+        consumes no RNG and is not counted in ``evicted``."""
         holder = self.owner.get(fp)
         if holder is None:
             return None
@@ -599,7 +694,7 @@ class PrioritizedCache:
         pba = sub.peek(fp)
         sub.remove(fp)
         del self.owner[fp]
-        self.index.discard(fp)
+        self._drops.append(fp)
         self.total -= 1
         if len(sub) == 0:
             self.segments.set_weight(holder, 0.0)
@@ -622,7 +717,7 @@ class PrioritizedCache:
         sub = self._sub(stream)
         sub.insert(fp, pba)
         self.owner[fp] = stream
-        self.index.add(fp)
+        self._adds.append(fp)
         self.total += 1
         if len(sub) == 1:
             self.segments.set_weight(stream, self._evict_priority(stream))

@@ -650,6 +650,61 @@ class FingerprintIndex(MutableSet):
             # stays correct in both cases
             self._pending_removes[fp] = None
 
+    def add_discard(self, adds, discards) -> None:
+        """The scalar ``add`` and ``discard`` calls, in bulk.
+
+        ``adds`` and ``discards`` each keep the order their calls were made
+        in, and the calls must have been a sequence in which every ``add`` is
+        of a non-member and every ``discard`` of a member (a membership
+        mirror's admissions and evictions).  Any such sequence that keeps the
+        two lists' orders leaves every buffer the same, dict order included,
+        so the result is exactly that of the calls as they were made.  The
+        common case — no key in both lists, none twice, no sentinel key —
+        takes whole-list dict updates; otherwise the calls are replayed in a
+        valid order.
+        """
+        keys = self._keys
+        new = dict.fromkeys(adds)
+        gone = dict.fromkeys(discards)
+        if (len(new) != len(adds) or len(gone) != len(discards)
+                or not new.keys().isdisjoint(gone)
+                or EMPTY_KEY in new or TOMB_KEY in new or EMPTY_KEY in gone or TOMB_KEY in gone):
+            # greedy replay: take the next add whenever its key is not a
+            # member, else the next discard, which is then of a member
+            i = j = 0
+            na, nd = len(adds), len(discards)
+            while i < na or j < nd:
+                if i < na and (j == nd or adds[i] not in keys):
+                    self.add(adds[i])
+                    i += 1
+                else:
+                    self.discard(discards[j])
+                    j += 1
+            return
+        if gone:
+            for k in gone:
+                del keys[k]
+            spill, staged = self._spill, self._pending_adds
+            hit = spill.intersection(gone) if spill else ()
+            unstaged = staged.keys() & gone if staged else ()
+            if hit:
+                spill.difference_update(hit)
+            for k in unstaged:
+                del staged[k]
+            if hit or unstaged:
+                gone = dict.fromkeys(k for k in gone if k not in hit and k not in unstaged)
+            self._pending_removes.update(gone)
+        if new:
+            keys.update(new)
+            removes = self._pending_removes
+            # a key whose tombstone is still pending sits in the table
+            back = removes.keys() & new if removes else ()
+            for k in back:
+                del removes[k]
+            if back:
+                new = dict.fromkeys(k for k in new if k not in back)
+            self._pending_adds.update(new)
+
     def remove(self, fp: int) -> None:
         if fp not in self._keys:
             raise KeyError(fp)

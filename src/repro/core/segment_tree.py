@@ -3,7 +3,9 @@
 Evict priorities ``p_i = 1 / LDSS_i`` are mapped to adjacent non-overlapping
 segments ``[sum_{k<i} p_k, sum_{k<=i} p_k)``; eviction draws ``r`` uniform in
 ``[0, sum p)`` and picks the stream whose segment contains ``r``.  A Fenwick
-(binary indexed) tree gives O(log M) weight updates and prefix-search draws.
+(binary indexed) tree gives O(log M) weight updates and prefix-search draws;
+the total ``sum p`` is kept current by every weight update, so a draw does
+not re-sum it.
 """
 
 from __future__ import annotations
@@ -29,6 +31,8 @@ class FenwickSegments:
         self._slot_of: Dict[int, int] = {}
         self._stream_of: Dict[int, int] = {}
         self._free = list(range(self._size - 1, -1, -1))
+        # sum of all weights, as ``_prefix(_size)`` computes it (read-only)
+        self.total = 0.0
 
     # -- slot management ----------------------------------------------------
     def _grow(self) -> None:
@@ -38,6 +42,7 @@ class FenwickSegments:
         self._free.extend(range(self._size - 1, old_size - 1, -1))
         for stream, slot in self._slot_of.items():
             self._add(slot, self._weights[stream])
+        self.total = self._prefix(self._size)
 
     def _add(self, slot: int, delta: float) -> None:
         tree = self._tree
@@ -64,6 +69,7 @@ class FenwickSegments:
             self._weights[stream] = 0.0
         slot = self._slot_of[stream]
         self._add(slot, weight - self._weights[stream])
+        self.total = self._prefix(self._size)
         self._weights[stream] = weight
         if weight == 0.0:
             del self._weights[stream]
@@ -76,10 +82,15 @@ class FenwickSegments:
 
     def draw(self, rng: np.random.Generator) -> Optional[int]:
         """Sample a stream with probability proportional to its weight."""
-        tot = self._prefix(self._size)
+        tot = self.total
         if tot <= 0.0:
             return None
-        r = rng.uniform(0.0, tot)
+        return self.locate(rng.uniform(0.0, tot))
+
+    def locate(self, r: float) -> int:
+        """The stream whose segment contains ``r`` (``0 <= r < total``):
+        ``draw`` with the uniform given, so a caller that draws its own
+        ``tot * u`` gets the victim ``uniform(0.0, tot)`` would pick."""
         # Fenwick prefix search: find the smallest slot with prefix sum > r
         tree = self._tree
         size = self._size
@@ -106,9 +117,6 @@ class FenwickSegments:
             s += tree[i]
             i -= i & (-i)
         return float(s)
-
-    def total_weight(self) -> float:
-        return self._prefix(self._size)
 
     def streams(self):
         return list(self._weights.keys())
@@ -142,4 +150,5 @@ class FenwickSegments:
             seg._slot_of[s] = slot
             seg._stream_of[slot] = s
             seg._weights[s] = weights[s]
+        seg.total = seg._prefix(seg._size)
         return seg

@@ -450,3 +450,73 @@ def test_add_many_after_pending_discard_stays_resident(backend):
     np.testing.assert_array_equal(idx.contains_many(np.array([1], dtype=np.uint64)), [True])
     assert set(idx) == {1}
     idx.check_consistency()
+
+
+# ---------------------------------------------------------------------------
+# The bulk form of add/discard against the scalar calls in order.
+# ---------------------------------------------------------------------------
+
+
+def _shared_home_keys(n, cap=1 << 12, seed=0):
+    """``n`` keys with one home slot in every table of up to ``cap`` slots:
+    past ``WINDOW`` of them, the rest spill."""
+    keys = _keys(np.random.default_rng(seed), 1 << 18)
+    keys = keys[(keys != EMPTY_KEY) & (keys != TOMB_KEY)]
+    lo = (keys & np.uint64(0xFFFFFFFF)).astype(np.uint32)
+    hi = (keys >> np.uint64(32)).astype(np.uint32)
+    home = slot_hash_host(lo, hi) & np.uint32(cap - 1)
+    best = np.bincount(home).argmax()
+    return keys[home == best][:n].tolist()
+
+
+def _buffers(idx):
+    return (list(idx._keys), list(idx._pending_adds), list(idx._pending_removes),
+            set(idx._spill))
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_add_discard_matches_the_scalar_calls_in_order(seed):
+    """Random interleavings of admissions (adds of non-members) and
+    evictions (discards of members), applied one call at a time to one index
+    and as the two ordered lists to another: every buffer matches, dict
+    order included, and each fold leaves the same table."""
+    rng = np.random.default_rng(seed)
+    crowd = _shared_home_keys(WINDOW + 6)
+    pool = [int(k) for k in _keys(rng, 120)] + crowd
+    scalar = FingerprintIndex(capacity=128, small_batch=0)
+    bulk = FingerprintIndex(capacity=128, small_batch=0)
+    members = []
+    for rnd in range(60):
+        # half the rounds touch each key once (the whole-list path); the
+        # others re-admit evicted keys, repeat keys and use the sentinels
+        once = rnd % 2 == 0
+        touched = set()
+        keys = pool if once else pool + [EMPTY_KEY, TOMB_KEY]
+        adds, drops = [], []
+        for _ in range(int(rng.integers(0, 50))):
+            if members and rng.random() < 0.45:
+                free = [k for k in members if not (once and k in touched)]
+                if not free:
+                    continue
+                k = free[int(rng.integers(0, len(free)))]
+                members.remove(k)
+                scalar.discard(k)
+                drops.append(k)
+            else:
+                free = [k for k in keys if k not in members and not (once and k in touched)]
+                if not free:
+                    continue
+                k = free[int(rng.integers(0, len(free)))]
+                members.append(k)
+                scalar.add(k)
+                adds.append(k)
+            touched.add(k)
+        bulk.add_discard(adds, drops)
+        assert _buffers(bulk) == _buffers(scalar)
+        assert set(bulk) == set(members)
+        if rng.random() < 0.5:
+            scalar.check_consistency()
+            bulk.check_consistency()
+            np.testing.assert_array_equal(bulk._t64, scalar._t64)
+            assert bulk.table_stats() == scalar.table_stats()
+    assert scalar.spilled() > 0

@@ -30,7 +30,7 @@ def test_grow_beyond_initial_capacity():
     t = FenwickSegments(capacity=4)
     for s in range(40):
         t.set_weight(s, float(s + 1))
-    assert abs(t.total_weight() - sum(range(1, 41))) < 1e-9
+    assert abs(t.total - sum(range(1, 41))) < 1e-9
     rng = np.random.default_rng(2)
     assert t.draw(rng) in range(40)
 
